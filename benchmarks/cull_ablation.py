@@ -22,6 +22,7 @@ from typing import List
 
 from benchmarks.common import camera, scenes, trajectory
 from benchmarks.wallclock import CULL_THRESHOLDS, cull_ablation_rows
+from repro.compile_cache import enable_compile_cache
 
 N_FRAMES = 8
 SMOKE_THRESHOLD = 0.05
@@ -56,6 +57,7 @@ def smoke() -> List[dict]:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="scoped-down pass with hard assertions (CI)")

@@ -12,11 +12,14 @@ import json
 import os
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 BENCHES = ("intersection", "warp_quality", "window_sweep", "ablation",
            "accelerator", "wallclock", "serve_bench", "cull_ablation")
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", choices=BENCHES, default=None)
     args = ap.parse_args()

@@ -42,6 +42,7 @@ from typing import List, Optional
 import jax
 
 from benchmarks.common import camera, scenes
+from repro.compile_cache import enable_compile_cache
 from repro.core.pipeline import RenderConfig
 from repro.obs.trace import validate_chrome_trace
 from repro.scenes.synthetic import random_blob_scene, structured_scene
@@ -343,6 +344,7 @@ def run_replay(smoke: bool = False, pattern: str = "skewed") -> List[dict]:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="CI configuration: tiny scene, 4 streams, "

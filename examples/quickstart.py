@@ -14,6 +14,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.camera import look_at, make_camera
 from repro.core.engine import render_trajectory
 from repro.core.pipeline import RenderConfig, render_full_frame
@@ -29,6 +30,7 @@ def save_ppm(path: str, img) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="/tmp/quickstart.ppm")
     ap.add_argument("--size", type=int, default=256)

@@ -9,12 +9,14 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCH_IDS, get_config
 from repro.models import model as M
 from repro.train.serve_step import greedy_generate
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="minicpm3-4b")
     ap.add_argument("--batch", type=int, default=4)

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.camera import make_camera
 from repro.core.engine import render_streams, render_trajectory
 from repro.core.metrics import psnr, ssim
@@ -36,6 +37,7 @@ from repro.scenes.trajectory import dolly_trajectory
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--frames", type=int, default=20)
     ap.add_argument("--window", type=int, default=5)

@@ -8,6 +8,7 @@ stream, checkpointing every 100; rerunning the same command auto-resumes.
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCH_IDS, get_config
 from repro.launch.train import RunConfig, train_loop
 from repro.train.data import DataConfig
@@ -15,6 +16,7 @@ from repro.train.optimizer import OptimizerConfig
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="yi-9b")
     ap.add_argument("--steps", type=int, default=300)

@@ -15,6 +15,17 @@ import numpy as np
 
 TILE = 16  # 16x16-pixel tiles, as in the paper (Sec. II-A)
 
+# Geometry matmuls run at full f32. On TPU the default precision rounds
+# f32 operands to bf16 (8 mantissa bits): a 6 m depth then lands on a
+# 3 cm grid, ties in depth become common, and reprojection misses by
+# pixels at 1080p. CPU matmuls are f32 either way.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def matmul(a, b):
+    """``a @ b`` at full f32 precision on every backend."""
+    return jnp.matmul(a, b, precision=HIGHEST)
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -65,7 +76,7 @@ def look_at(eye, target, up=(0.0, 1.0, 0.0)) -> jax.Array:
     right = right / (jnp.linalg.norm(right) + 1e-12)
     down = jnp.cross(fwd, right)  # y points down in camera frame
     rot = jnp.stack([right, down, fwd], axis=0)  # (3, 3) world->cam rotation
-    trans = -rot @ eye
+    trans = -matmul(rot, eye)
     w2c = jnp.eye(4, dtype=jnp.float32)
     w2c = w2c.at[:3, :3].set(rot).at[:3, 3].set(trans)
     return w2c
@@ -74,14 +85,15 @@ def look_at(eye, target, up=(0.0, 1.0, 0.0)) -> jax.Array:
 def camera_position(cam: Camera) -> jax.Array:
     """Camera center in world coordinates. (3,)."""
     rot = cam.w2c[:3, :3]
-    return -rot.T @ cam.w2c[:3, 3]
+    return -matmul(rot.T, cam.w2c[:3, 3])
 
 
 def cam_to_world(cam: Camera) -> jax.Array:
     """(4, 4) inverse pose."""
     rot = cam.w2c[:3, :3]
     c2w = jnp.eye(4, dtype=cam.w2c.dtype)
-    c2w = c2w.at[:3, :3].set(rot.T).at[:3, 3].set(-rot.T @ cam.w2c[:3, 3])
+    c2w = c2w.at[:3, :3].set(rot.T).at[:3, 3].set(
+        -matmul(rot.T, cam.w2c[:3, 3]))
     return c2w
 
 
@@ -102,13 +114,13 @@ def backproject(cam: Camera, depth: jax.Array) -> jax.Array:
     y = (v - cam.cy) / cam.fy * depth
     pts_cam = jnp.stack([x, y, depth], axis=-1)            # (H, W, 3)
     rot = cam.w2c[:3, :3]
-    return (pts_cam - cam.w2c[:3, 3]) @ rot  # == rot.T @ (p - t), batched
+    return matmul(pts_cam - cam.w2c[:3, 3], rot)  # == rot.T @ (p - t)
 
 
 def project(cam: Camera, pts_world: jax.Array):
     """World points -> (u, v, depth). pts_world: (..., 3)."""
     rot, t = cam.w2c[:3, :3], cam.w2c[:3, 3]
-    pc = pts_world @ rot.T + t
+    pc = matmul(pts_world, rot.T) + t
     z = pc[..., 2]
     safe_z = jnp.where(jnp.abs(z) < 1e-8, 1e-8, z)
     u = cam.fx * pc[..., 0] / safe_z + cam.cx

@@ -14,6 +14,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.camera import matmul
+
 # Real SH basis constants (degree <= 3), matching the reference 3DGS CUDA
 # implementation.
 SH_C0 = 0.28209479177387814
@@ -73,7 +75,7 @@ def covariances(scene: GaussianScene) -> jax.Array:
     rot = quat_to_rotmat(scene.quats)                     # (N, 3, 3)
     scale = jnp.exp(scene.log_scales)                      # (N, 3)
     m = rot * scale[:, None, :]                            # R @ diag(s)
-    return m @ jnp.swapaxes(m, -1, -2)
+    return matmul(m, jnp.swapaxes(m, -1, -2))
 
 
 def eval_sh(sh: jax.Array, dirs: jax.Array) -> jax.Array:

@@ -100,8 +100,14 @@ def tait_mask(proj: ProjectedGaussians, grid: TileGrid) -> jax.Array:
     stage1 = tait_stage1_mask(proj, grid)
     # Stage 2: component of (tile center - ellipse center) along the minor
     # axis. Reject when it exceeds R_minor + tile circumradius (safe form).
-    d = grid.centers[None, :, :] - proj.mean2d[:, None, :]      # (N, T, 2)
-    along_minor = jnp.abs(jnp.einsum("ntc,nc->nt", d, proj.minor_axis))
+    # Elementwise, not an einsum: it fuses into the mask instead of
+    # materialising an (N, T, 2) float tensor (4.3 GB at 65,536
+    # Gaussians x 1080p), and stays f32 where a TPU dot would round to
+    # bf16.
+    dx = grid.centers[None, :, 0] - proj.mean2d[:, 0:1]          # (N, T)
+    dy = grid.centers[None, :, 1] - proj.mean2d[:, 1:2]
+    along_minor = jnp.abs(dx * proj.minor_axis[:, 0:1]
+                          + dy * proj.minor_axis[:, 1:2])
     keep = along_minor - TILE_CIRCUMRADIUS <= proj.r_minor[:, None]
     return stage1 & keep
 

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import gaussians as G
-from repro.core.camera import Camera, camera_position
+from repro.core.camera import Camera, camera_position, matmul
 
 # Opacity threshold below which a Gaussian does not contribute (1/255),
 # Sec. II-A / eq. (4).
@@ -69,7 +69,7 @@ def preprocess(scene: G.GaussianScene, cam: Camera, *,
     the image can still splat into it).
     """
     rot, t = cam.w2c[:3, :3], cam.w2c[:3, 3]
-    p_cam = scene.means @ rot.T + t                       # (N, 3)
+    p_cam = matmul(scene.means, rot.T) + t                # (N, 3)
     z = p_cam[..., 2]
     safe_z = jnp.maximum(z, near)
 
@@ -91,8 +91,8 @@ def preprocess(scene: G.GaussianScene, cam: Camera, *,
     ], axis=-2)                                            # (N, 2, 3)
 
     cov3d = G.covariances(scene)                           # (N, 3, 3)
-    m = j @ rot[None, :, :]                                # (N, 2, 3)
-    cov2d_full = m @ cov3d @ jnp.swapaxes(m, -1, -2)       # (N, 2, 2)
+    m = matmul(j, rot[None, :, :])                         # (N, 2, 3)
+    cov2d_full = matmul(matmul(m, cov3d), jnp.swapaxes(m, -1, -2))
     a = cov2d_full[..., 0, 0] + dilation
     b = cov2d_full[..., 0, 1]
     c = cov2d_full[..., 1, 1] + dilation

@@ -34,7 +34,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.camera import TILE, Camera, backproject
+from repro.core.camera import TILE, Camera, backproject, matmul
 from repro.core.raster import tile_view, untile
 
 # A pixel is a usable reprojection source only if enough opacity
@@ -88,7 +88,7 @@ def _project_points(ref_cam: Camera, depth_map: jax.Array, mask: jax.Array,
     h, w = depth_map.shape
     pts = backproject(ref_cam, depth_map)                   # (H, W, 3)
     rot, t = tgt_cam.w2c[:3, :3], tgt_cam.w2c[:3, 3]
-    pc = pts.reshape(-1, 3) @ rot.T + t
+    pc = matmul(pts.reshape(-1, 3), rot.T) + t
     z = pc[:, 2]
     u = tgt_cam.fx * pc[:, 0] / jnp.maximum(z, near) + tgt_cam.cx
     v = tgt_cam.fy * pc[:, 1] / jnp.maximum(z, near) + tgt_cam.cy

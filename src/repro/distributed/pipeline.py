@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from jax.experimental.shard_map import shard_map
-
 
 def pipeline_apply(layer_fn: Callable, params_stacked, x, *,
                    mesh: Mesh, num_micro: int, axis: str = "pod"):
@@ -88,10 +86,10 @@ def pipeline_apply(layer_fn: Callable, params_stacked, x, *,
 
     param_specs = jax.tree_util.tree_map(
         lambda l: P(axis, *([None] * (l.ndim - 1))), params_stacked)
-    fn = shard_map(staged, mesh=mesh,
-                   in_specs=(param_specs, P(axis)),
-                   out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(staged, mesh=mesh,
+                       in_specs=(param_specs, P(axis)),
+                       out_specs=P(),
+                       check_vma=False)
     return fn(params_stacked, x)
 
 

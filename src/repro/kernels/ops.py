@@ -20,9 +20,10 @@ import jax.numpy as jnp
 
 from repro.core.camera import TILE
 from repro.kernels import ref as ref_kernels
-from repro.kernels.raster_tile import (ALPHA_MAX, ALPHA_MIN, T_EPS,
-                                       raster_tiles_pallas)
-from repro.kernels.raster_plan import raster_plan_fused
+from repro.kernels.raster_tile import raster_tiles_pallas
+from repro.kernels.raster_plan import (blend_chunk, finish_blend,
+                                       init_blend_state, pack_attributes,
+                                       pixel_centers, raster_plan_fused)
 from repro.kernels.preprocess import preprocess_geom_pallas
 from repro.obs.trace import annotate
 
@@ -45,61 +46,27 @@ def default_impl() -> str:
 
 def _raster_tile_chunked_jnp(mean2d, conic, rgb, opacity, depth, origin,
                              count, *, chunk: int, tile: int):
-    """One tile, chunked math identical to the Pallas kernel, pure jnp."""
+    """One tile in pure jnp: the fused kernel's ``blend_chunk`` over
+    pre-sorted lanes, chunk by chunk (same math, same summation order)."""
     k = opacity.shape[0]
-    ii = jnp.arange(tile, dtype=jnp.float32)
-    py_g, px_g = jnp.meshgrid(ii, ii, indexing="ij")
-    px = px_g.ravel() + origin[0] + 0.5
-    py = py_g.ravel() + origin[1] + 0.5
-    p = tile * tile
+    px, py = pixel_centers(origin[0], origin[1], tile)
+    roll = functools.partial(jnp.roll, axis=1)
 
-    def body(carry, sl):
-        c_acc, t_run, done, d_acc, w_acc, td_max, n_alive = carry
-        alive = jnp.any(~done)
-        mx, my = sl["m"][:, 0], sl["m"][:, 1]
-        ca, cb, cc = sl["c"][:, 0], sl["c"][:, 1], sl["c"][:, 2]
-        dx = px[:, None] - mx[None, :]
-        dy = py[:, None] - my[None, :]
-        power = (-0.5 * (ca[None] * dx * dx + cc[None] * dy * dy)
-                 - cb[None] * dx * dy)
-        alpha = jnp.minimum(sl["o"][None, :] * jnp.exp(power), ALPHA_MAX)
-        alpha = jnp.where(alpha >= ALPHA_MIN, alpha, 0.0)
-        cp = jnp.cumprod(1.0 - alpha, axis=1)
-        tp = t_run[:, None] * cp
-        t_before = t_run[:, None] * jnp.concatenate(
-            [jnp.ones_like(cp[:, :1]), cp[:, :-1]], axis=1)
-        blend = (tp >= T_EPS) & (~done[:, None])    # sticky done, see kernel
-        w = jnp.where(blend, alpha * t_before, 0.0)
-        c_acc = c_acc + w @ sl["rgb"]
-        d_acc = d_acc + jnp.sum(w * sl["d"][None, :], axis=1)
-        w_acc = w_acc + jnp.sum(w, axis=1)
-        td_max = jnp.maximum(td_max, jnp.max(
-            jnp.where(blend & (alpha > 0.0), sl["d"][None, :], 0.0), axis=1))
-        t_run = jnp.min(jnp.where(blend, tp, t_run[:, None]), axis=1)
-        done = done | (tp[:, -1] < T_EPS)
-        n_alive = n_alive + alive.astype(jnp.int32)
-        # Per-lane blend contribution: sum of w over the tile's pixels —
-        # identical math to the fused kernel's accumulator, so the two
-        # impls agree bit-for-bit on matching inputs.
-        return (c_acc, t_run, done, d_acc, w_acc, td_max, n_alive), \
-            jnp.sum(w, axis=0)
+    def body(carry, blk):
+        st, n_alive = carry
+        alive = jnp.any(st.done == 0.0)
+        st, contrib = blend_chunk(px, py, blk, st, roll)
+        return (st, n_alive + alive.astype(jnp.int32)), contrib[0]
 
-    n_chunks = k // chunk
-    xs = {
-        "m": mean2d.reshape(n_chunks, chunk, 2),
-        "c": conic.reshape(n_chunks, chunk, 3),
-        "rgb": rgb.reshape(n_chunks, chunk, 3),
-        "o": opacity.reshape(n_chunks, chunk),
-        "d": depth.reshape(n_chunks, chunk),
-    }
-    init = (jnp.zeros((p, 3)), jnp.ones((p,)), jnp.zeros((p,), bool),
-            jnp.zeros((p,)), jnp.zeros((p,)), jnp.zeros((p,)), jnp.int32(0))
-    (c_acc, t_run, done, d_acc, w_acc, td_max, n_alive), contrib = \
-        jax.lax.scan(body, init, xs)
+    blocks = pack_attributes(mean2d, conic, rgb, opacity, depth, k)
+    blocks = blocks.reshape(-1, k // chunk, chunk).swapaxes(0, 1)
+    (st, n_alive), contrib = jax.lax.scan(
+        body, (init_blend_state(tile * tile), jnp.int32(0)), blocks)
     processed = jnp.minimum(n_alive * chunk, count).astype(jnp.int32)
-    return (c_acc.reshape(tile, tile, 3), t_run.reshape(tile, tile),
-            (d_acc / jnp.maximum(w_acc, 1e-8)).reshape(tile, tile),
-            td_max.reshape(tile, tile), processed, contrib.reshape(k))
+    rgb_o, trans, exp_depth, trunc_depth = finish_blend(st)
+    return (rgb_o.reshape(tile, tile, 3), trans.reshape(tile, tile),
+            exp_depth.reshape(tile, tile), trunc_depth.reshape(tile, tile),
+            processed, contrib.reshape(k))
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "chunk", "tile"))

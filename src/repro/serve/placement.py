@@ -32,7 +32,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import engine
@@ -111,11 +110,11 @@ def build_render_fn(cam: Camera, cfg: RenderConfig,
             return carry_end, frames, recs, active
 
         sharded = P("streams")
-        smapped = jax.jit(shard_map(
+        smapped = jax.jit(jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(P(), sharded, sharded, sharded, sharded, sharded),
             out_specs=(sharded, sharded, sharded, sharded),
-            check_rep=False))
+            check_vma=False))
 
         def fn(scenes, poses, counts, phases, carries, slot_scene):
             counts = jnp.asarray(counts, jnp.int32)
@@ -146,11 +145,11 @@ def build_render_fn(cam: Camera, cfg: RenderConfig,
         return carry_end, frames, recs, active
 
     sharded = P("streams")
-    smapped = jax.jit(shard_map(
+    smapped = jax.jit(jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), sharded, sharded, sharded, sharded),
         out_specs=(sharded, sharded, sharded, sharded),
-        check_rep=False))
+        check_vma=False))
 
     def fn(scene, poses, counts, phases, carries):
         counts = jnp.asarray(counts, jnp.int32)

@@ -320,6 +320,12 @@ class StreamServer:
         self._m_culled = m.histogram(
             "device_culled_pairs", "pairs removed by contribution culling",
             keep=scfg.history)
+        self._m_overflow_tiles = m.counter(
+            "serve_overflow_tiles_total",
+            "re-render tiles beyond R, interpolated instead")
+        self._m_overflow_pairs = m.counter(
+            "serve_overflow_pairs_total",
+            "intersection pairs beyond the bin capacity K, dropped")
         self._m_demand = m.histogram(
             "device_rerender_demand",
             "re-render tiles wanted per sparse frame (pre-cap)",
@@ -664,6 +670,10 @@ class StreamServer:
             t.reshape(-1, t.shape[-1]).sum(axis=-1)[mask])
         self._m_culled.observe_many(
             np.asarray(recs.culled_pairs).reshape(-1)[mask])
+        self._m_overflow_pairs.inc(int(
+            np.asarray(recs.overflow_pairs).reshape(-1)[mask].sum()))
+        self._m_overflow_tiles.inc(int(
+            np.asarray(recs.overflow_tiles).reshape(-1)[sparse].sum()))
         if sparse.any():
             demand = np.asarray(rerender_demand(
                 recs.active, recs.overflow_tiles)).reshape(-1)

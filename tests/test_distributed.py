@@ -115,7 +115,6 @@ def test_int8_compressed_psum_error_feedback():
         import jax, jax.numpy as jnp
         import numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distributed.compression import (compressed_psum,
                                                    zero_residuals)
 
@@ -125,9 +124,9 @@ def test_int8_compressed_psum_error_feedback():
         def step(x, r):
             return compressed_psum(x, "data", r)
 
-        f = shard_map(step, mesh=mesh,
-                      in_specs=(P("data"), P("data")),
-                      out_specs=(P("data"), P("data")))
+        f = jax.shard_map(step, mesh=mesh,
+                          in_specs=(P("data"), P("data")),
+                          out_specs=(P("data"), P("data")))
         exact = jnp.mean(g, axis=0)
         res = jnp.zeros_like(g)
         errs = []

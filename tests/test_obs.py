@@ -277,10 +277,20 @@ def test_frame_parity_across_chunks_with_tracing(small_cam):
     chunk seams."""
     srv, entry = _server(small_cam, True, collect_frames=True)
     sess = srv.attach(_poses(5), scene_id=entry.scene_id)
-    srv.run(max_rounds=20)
+    report = srv.run(max_rounds=20)
     got = np.concatenate(sess.frames)
     solo = engine.render_trajectory(
         entry.scene, small_cam, jax.numpy.asarray(_poses(5)),
         RenderConfig(window=3, capacity=128, rerender_capacity=8),
         phase=sess.phase)
     np.testing.assert_allclose(got, np.asarray(solo.frames), atol=1e-5)
+    # The overflow counters sum the same records across chunk seams:
+    # pairs past K on every frame, tiles past R on sparse frames.
+    counters = report["metrics"]["counters"]
+    recs = solo.records
+    sparse = ~np.asarray(recs.is_full)
+    assert counters["serve_overflow_pairs_total"] == int(
+        np.asarray(recs.overflow_pairs).sum())
+    assert counters["serve_overflow_tiles_total"] == int(
+        np.asarray(recs.overflow_tiles)[sparse].sum())
+    assert counters["serve_overflow_tiles_total"] > 0   # R = 8 of 16 tiles

@@ -99,6 +99,23 @@ def test_fused_sorts_in_kernel(small_scene, small_cam):
         np.testing.assert_array_equal(contrib_shuf[r, c:], 0.0)
 
 
+def test_fused_keeps_input_order_on_depth_ties(small_scene, small_cam):
+    """Equal depths blend in input lane order, as ``jnp_chunked`` does.
+
+    Quantizing sorted depths keeps them sorted and makes ties common
+    (bf16-rounded TPU geometry once did); an unstable sort would then
+    blend tied Gaussians in another order than the binning chose."""
+    mean2d, conic, rgb, opacity, depth, origins, counts = _tile_inputs(
+        small_scene, small_cam, 64)
+    tied = jnp.floor(depth * 2.0) / 2.0
+    assert np.sum(np.diff(np.asarray(tied), axis=1) == 0) > 100
+    args = (mean2d, conic, rgb, opacity, tied, origins, counts)
+    o_jnp = ops.raster_tiles(*args, impl="jnp_chunked", chunk=32)
+    o_fused = ops.raster_tiles(*args, impl="pallas_fused", chunk=32)
+    for a, b in zip(o_fused, o_jnp):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_masked_slots_render_empty(small_scene, small_cam):
     """slot_active=False slots (counts zeroed, the plan contract) read
     as empty: rgb 0, T=1, 0 processed pairs; active slots unchanged."""
