@@ -1,0 +1,11 @@
+"""Device time under ``repro.frame/raster``, the raster stage, in ms per frame
+delivered in the traced rounds, summed over devices."""
+
+SCOPE = "repro.frame/raster"
+
+
+def read(ctx):
+    seconds = ctx.trace.scope_s(SCOPE)
+    if seconds <= 0 or not ctx.frames:
+        return None
+    return 1e3 * seconds / len(ctx.frames)
