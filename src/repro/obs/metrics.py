@@ -11,7 +11,7 @@ three instrument kinds (DESIGN.md §13):
   ``set_max`` keeps a running maximum (``serve_max_concurrent_streams``).
 - **Histogram** — lifetime ``count``/``sum``/``min``/``max`` plus a
   bounded newest-``keep`` reservoir for percentiles
-  (``device_sort_pairs``). An empty histogram reports ``None``
+  (``serve_latency_seconds``). An empty histogram reports ``None``
   percentiles, never NaN — callers can snapshot before the first
   observation.
 

@@ -16,7 +16,11 @@ Overhead contract:
   its ``with tracer.span(...)`` lines unconditionally; a disabled tracer
   makes them free.
 - **Enabled**: two ``perf_counter_ns`` reads per span plus one locked
-  list append at span *exit* (so a span's body never holds the lock).
+  list append at span *exit* (so a span's body never holds the lock),
+  and a ``jax.profiler.TraceAnnotation`` named ``serve/<name>`` around
+  the body, so a device trace taken with ``jax.profiler`` carries the
+  program's spans on its own clock and an idle stretch of the device
+  can be put down to the host phase that covers it.
   The buffer is bounded by ``keep``: the **earliest** events are
   retained (a serve run's compile spans land early — they are the ones
   CI asserts on) and later events are counted in ``dropped``.
@@ -64,9 +68,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span: timestamps on enter/exit, emits a complete event."""
+    """One live span: timestamps on enter/exit, emits a complete event,
+    and holds the body's profiler annotation open."""
 
-    __slots__ = ("_tracer", "_name", "_track", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_track", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, track: str,
                  args: Optional[dict]):
@@ -76,11 +81,14 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        self._ann = jax.profiler.TraceAnnotation("serve/" + self._name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
         self._tracer._emit_complete(self._name, self._track, self._t0, t1,
                                     self._args)
         return False
@@ -114,7 +122,8 @@ class Tracer:
     # -- recording ---------------------------------------------------------
     def span(self, name: str, track: str = "main",
              args: Optional[dict] = None):
-        """Context manager timing its body as one complete event."""
+        """Context manager timing its body as one complete event, under a
+        ``serve/<name>`` profiler annotation."""
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, track, args)

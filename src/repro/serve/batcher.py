@@ -30,14 +30,18 @@ Two serving axes beyond the fixed-B original (DESIGN.md §10):
   tests/test_serve_scenes.py).
 
 ``build`` pops poses (and their enqueue stamps) out of the sessions;
-``commit`` writes back the final carries, stamps per-frame latencies,
-optionally retains rendered frames on the session
-(``collect_frames=True``), and releases slots of drained-and-closed
-sessions (detaching them from the manager).
+``commit`` writes back the final carries, optionally copies the rendered
+frames to host memory and retains them on the session
+(``collect_frames=True``), stamps per-frame latencies once the pixels
+are there, and releases slots of drained-and-closed sessions (detaching
+them from the manager). With a tracer on, ``build`` times its ``stack``
+and ``upload`` phases and ``commit`` its ``carry`` write-backs and
+per-slot ``fetch`` copies, each nested in the server's ``build`` or
+``commit`` span on the group's track.
 """
 from __future__ import annotations
 
-from typing import List, NamedTuple, Optional, Set, Tuple
+from typing import Callable, List, NamedTuple, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +51,7 @@ from repro.core import engine
 from repro.core.camera import Camera
 from repro.core.engine import EngineCarry, StreamsResult
 from repro.obs.trace import NULL_TRACER, Tracer
-from repro.serve.session import SessionManager
+from repro.serve.session import SessionManager, StreamSession
 
 _EYE = np.eye(4, dtype=np.float32)
 
@@ -71,6 +75,14 @@ class SlotBatch(NamedTuple):
     @property
     def bound_slots(self) -> int:
         return sum(s is not None for s in self.sids)
+
+
+class Committed(NamedTuple):
+    """What ``ContinuousBatcher.commit`` did with one group's result."""
+
+    detached: List[StreamSession]   # sessions drained and detached
+    delivered: float                # the latency stamp of the group's frames
+    fetch_bytes: int                # bytes of frames copied to host memory
 
 
 class ContinuousBatcher:
@@ -264,22 +276,36 @@ class ContinuousBatcher:
                 carries.append(self._idle_carry)
                 sids.append(None)
             stamps.append(tuple(slot_stamps))
-        stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *carries)
-        return SlotBatch(poses=jnp.asarray(poses),
-                         counts=jnp.asarray(counts),
-                         phases=jnp.asarray(phases), carries=stacked,
-                         sids=tuple(sids), enq_times=tuple(stamps),
-                         slot_scene=jnp.asarray(slot_scene),
+        tk = f"bucket {self.bucket}"
+        with self.tracer.span("stack", track=tk):
+            stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                             *carries)
+        with self.tracer.span("upload", track=tk):
+            poses, counts, phases, slot_scene = (
+                jnp.asarray(a) for a in (poses, counts, phases, slot_scene))
+        return SlotBatch(poses=poses, counts=counts, phases=phases,
+                         carries=stacked, sids=tuple(sids),
+                         enq_times=tuple(stamps), slot_scene=slot_scene,
                          scene_ids=tuple(scene_ids))
 
     def commit(self, batch: SlotBatch, result: StreamsResult,
-               manager: SessionManager, now: float) -> List["StreamSession"]:
-        """Write back carries/latencies; detach drained sessions.
+               manager: SessionManager, now: float,
+               clock: Optional[Callable[[], float]] = None) -> Committed:
+        """Write back carries, copy frames to host, stamp latencies;
+        detach drained sessions.
 
-        Returns the sessions detached this round (their slots free up for
-        the next ``admit``; the server keeps them for final stats).
+        ``now`` is when the device finished the group. A frame's latency
+        runs from its pose's enqueue stamp to its pixels in host memory:
+        where frames are copied (``collect_frames``) and ``clock`` is
+        given, that is ``clock()`` read after the group's last copy,
+        else ``now``. Returns the sessions detached this round (their
+        slots free up for the next ``admit``; the server keeps them for
+        final stats), the stamp, and the bytes copied.
         """
-        detached: List = []
+        tk = f"bucket {self.bucket}"
+        counts = np.asarray(batch.counts)
+        served: List[Tuple[int, StreamSession, int]] = []
+        fetched = 0
         for i, sid in enumerate(batch.sids):
             if sid is None:
                 continue
@@ -291,16 +317,25 @@ class ContinuousBatcher:
                     self._slot_sid[i] = None
                 continue
             sess = manager.sessions[sid]
-            sess.carry = jax.tree_util.tree_map(lambda a, i=i: a[i],
-                                                result.carries)
-            n = int(np.asarray(batch.counts)[i])
+            with self.tracer.span("carry", track=tk):
+                sess.carry = jax.tree_util.tree_map(lambda a, i=i: a[i],
+                                                    result.carries)
+            n = int(counts[i])
             sess.frames_rendered += n
             if self.collect_frames and n:
-                sess.frames.append(np.asarray(result.frames[i][:n]))
-            sess.latencies.extend(now - t for t in batch.enq_times[i][:n])
+                with self.tracer.span("fetch", track=tk):
+                    frames = np.asarray(result.frames[i][:n])
+                sess.frames.append(frames)
+                fetched += frames.nbytes
+            served.append((i, sess, n))
+        delivered = clock() if fetched and clock is not None else now
+        detached: List[StreamSession] = []
+        for i, sess, n in served:
+            sess.latencies.extend(delivered - t
+                                  for t in batch.enq_times[i][:n])
             if sess.done:
-                manager.detach(sid)
+                manager.detach(sess.sid)
                 sess.slot = None
                 self._slot_sid[i] = None
                 detached.append(sess)
-        return detached
+        return Committed(detached, delivered, fetched)

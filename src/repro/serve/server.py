@@ -106,8 +106,10 @@ class ServeConfig:
     sim_keep: int = 4096        # most recent frames kept for the sim
     # Observability (repro/obs, DESIGN.md §13): ``trace=True`` records
     # round/plan/resize/admit/build/dispatch/barrier/commit spans (one
-    # track per scene-bucket group) plus per-key compile spans, exported
-    # as Chrome-trace JSON via ``StreamServer.tracer``. Off by default —
+    # track per scene-bucket group), build's stack/upload and commit's
+    # carry/fetch/observe children, plus per-key compile spans, exported
+    # as Chrome-trace JSON via ``StreamServer.tracer`` and opened as
+    # ``serve/<span>`` profiler annotations. Off by default —
     # a disabled tracer's span() is a shared no-op. The metrics registry
     # is always on (host counters; report() composes its snapshot).
     trace: bool = False
@@ -262,6 +264,7 @@ class StreamServer:
 
     TRACE_KEEP = 1024     # most recent per-round dicts kept for report()
     LATENCY_KEEP = 65536  # most recent per-frame latency samples kept
+    LATENCY_HELP = "per-frame enqueue -> pixels in host memory"
     STACK_KEEP = 8        # memoized per-round scene stacks
 
     def __init__(self, scene: Union[GaussianScene, SceneRegistry],
@@ -297,8 +300,9 @@ class StreamServer:
         self._m_cap_frames = m.counter(
             "serve_capacity_frames_total",
             "sum of B*chunk slot-frames over rendered groups")
-        self._m_render_s = m.counter("serve_render_seconds_total",
-                                     "wall seconds inside serving rounds")
+        self._m_render_s = m.counter(
+            "serve_render_seconds_total",
+            "wall seconds of busy rounds, round start to last commit")
         self._m_warmup_s = m.counter("serve_warmup_seconds_total",
                                      "wall seconds inside warmup()")
         self._m_concurrent = m.gauge("serve_max_concurrent_streams",
@@ -306,20 +310,20 @@ class StreamServer:
         self._m_trace_drop = m.counter(
             "serve_rounds_trace_dropped_total",
             "per-round trace dicts evicted from the bounded deque")
+        self._m_fetch_bytes = m.counter(
+            "serve_fetch_bytes_total",
+            "bytes of rendered frames copied to host memory")
+        self._m_wait_s = m.counter(
+            "serve_wait_seconds_total",
+            "per real frame, its group's dispatch start less its enqueue")
         # Bounded latency/device-work histograms: lifetime count/sum are
         # exact, percentiles are over the newest LATENCY_KEEP samples —
         # finished StreamSession objects are NOT retained (a churning
         # server would otherwise grow memory without bound). Per-bucket
         # latency histograms feed the fairness split in report().
         self._m_latency = m.histogram(
-            "serve_latency_seconds", "per-frame enqueue -> render-complete",
+            "serve_latency_seconds", self.LATENCY_HELP,
             keep=self.LATENCY_KEEP)
-        self._m_sort_pairs = m.histogram(
-            "device_sort_pairs", "pairs entering the per-frame sort",
-            keep=scfg.history)
-        self._m_culled = m.histogram(
-            "device_culled_pairs", "pairs removed by contribution culling",
-            keep=scfg.history)
         self._m_overflow_tiles = m.counter(
             "serve_overflow_tiles_total",
             "re-render tiles beyond R, interpolated instead")
@@ -660,16 +664,10 @@ class StreamServer:
         recs = result.records
         mask = np.asarray(result.frame_active).reshape(-1)
         sparse = mask & ~np.asarray(recs.is_full).reshape(-1)
-        # Device-work histograms (DESIGN.md §13): per-frame sort pairs
-        # and culled pairs over real frames, re-render demand over real
-        # sparse frames — derived from the SAME device records the
-        # engine was already returning, so observing costs no extra
-        # transfers beyond the np.asarray the demand path always paid.
-        t = np.asarray(recs.sort_pairs)
-        self._m_sort_pairs.observe_many(
-            t.reshape(-1, t.shape[-1]).sum(axis=-1)[mask])
-        self._m_culled.observe_many(
-            np.asarray(recs.culled_pairs).reshape(-1)[mask])
+        # Overflow counters over real frames and the re-render demand
+        # histogram over real sparse frames (DESIGN.md §13), from the
+        # records the engine already returns; the demand transfer is the
+        # one the R policy needs anyway.
         self._m_overflow_pairs.inc(int(
             np.asarray(recs.overflow_pairs).reshape(-1)[mask].sum()))
         self._m_overflow_tiles.inc(int(
@@ -758,8 +756,7 @@ class StreamServer:
         ``serve_latency_seconds``) — get-or-create, so report() can read
         a bucket that never rendered and see None percentiles."""
         return self.metrics.histogram(
-            "serve_latency_seconds",
-            "per-frame enqueue -> render-complete",
+            "serve_latency_seconds", self.LATENCY_HELP,
             keep=self.LATENCY_KEEP, bucket=str(bucket))
 
     def _push_round(self, info: dict) -> None:
@@ -773,11 +770,11 @@ class StreamServer:
         self._m_rounds.inc()
         rnd = self.rounds
         tr = self.tracer
+        t0 = self.clock()
         with tr.span("round", track="round", args={"round": rnd}):
             with tr.span("plan", track="round"):
                 demand = self._bucket_demand()
                 plan = self.admission.plan_round(demand)
-            t0 = self.clock()
             # Launch every planned bucket group back to back (async
             # dispatch): group k+1's host-side batch build overlaps
             # group k's device execution, and the single barrier below
@@ -801,6 +798,12 @@ class StreamServer:
                 with tr.span("dispatch", track=tk,
                              args={"key": str(key),
                                    "frames": batch.active_frames}):
+                    # A frame waits from its enqueue stamp to its
+                    # group's dispatch.
+                    t_dispatch = self.clock()
+                    self._m_wait_s.inc(sum(t_dispatch - t
+                                           for ts in batch.enq_times
+                                           for t in ts))
                     scenes = self._stack_for(batch.scene_ids, bucket,
                                              bat.slots)
                     fn = self._executable(bucket, bat.slots)
@@ -826,19 +829,26 @@ class StreamServer:
             group_infos = []
             scene_ids_served: List[int] = []
             for bucket, bat, batch, result in groups:
-                with tr.span("commit", track=f"bucket {bucket}"):
-                    detached = bat.commit(batch, result, self.manager, t1)
+                tk = f"bucket {bucket}"
+                with tr.span("commit", track=tk):
+                    done = bat.commit(batch, result, self.manager, t1,
+                                      clock=self.clock)
+                    detached = done.detached
+                    self._m_fetch_bytes.inc(done.fetch_bytes)
                     for sess in detached:
                         self.registry.release(sess.scene_id)
                     self._m_finished.inc(len(detached))
                     counts = np.asarray(batch.counts)
                     blat = self._bucket_latency(bucket)
                     for i in range(len(batch.sids)):
-                        lats = [t1 - t
+                        lats = [done.delivered - t
                                 for t in batch.enq_times[i][:counts[i]]]
                         self._m_latency.observe_many(lats)
                         blat.observe_many(lats)
-                    self._observe(result)      # counts busy rounds
+                    # Opened here, not in _observe: whatever wraps the
+                    # method is timed with it.
+                    with tr.span("observe", track=tk):
+                        self._observe(result)      # counts busy rounds
                     if self.scfg.sim_latency:
                         self._record_sim(batch, result)
                     self.admission.record_service(bucket,
@@ -854,13 +864,14 @@ class StreamServer:
                         "bound_slots": batch.bound_slots,
                         "slots": bat.slots,
                         "scene_ids": ids, "detached": len(detached)})
-            self._m_render_s.inc(t1 - t0)
+            t_end = self.clock()
+            self._m_render_s.inc(t_end - t0)
         info = {"round": rnd, "frames": total_frames,
                 "bound_slots": sum(g["bound_slots"] for g in group_infos),
                 "groups": group_infos,
                 "scene_ids": scene_ids_served,
                 "capacity": self.capacity,
-                "render_seconds": round(t1 - t0, 4),
+                "render_seconds": round(t_end - t0, 4),
                 "detached": sum(g["detached"] for g in group_infos)}
         if len(group_infos) == 1:
             # Single-group rounds keep the legacy flat fields.

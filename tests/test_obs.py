@@ -60,6 +60,45 @@ def test_tracer_records_spans_and_instants():
     assert names == {"round", "bucket (512, 4)"}
 
 
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs what opens."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+        return _Ann()
+
+
+def test_tracer_spans_open_profiler_annotations(monkeypatch):
+    """An enabled span runs under one ``serve/<name>`` profiler
+    annotation; a disabled tracer returns the shared no-op and makes no
+    profiler call at all."""
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    off = Tracer(enabled=False)
+    assert off.span("round") is NULL_TRACER.span("x")
+    with off.span("round"):
+        with off.span("fetch", track="bucket b"):
+            pass
+    assert ann.log == []
+    on = Tracer(enabled=True)
+    with on.span("round"):
+        with on.span("fetch", track="bucket b"):
+            pass
+    assert ann.log == [("enter", "serve/round"), ("enter", "serve/fetch"),
+                       ("exit", "serve/fetch"), ("exit", "serve/round")]
+    assert [e["name"] for e in on.events()] == ["fetch", "round"]
+
+
 def test_tracer_buffer_bounded_keeps_first():
     tr = Tracer(enabled=True, keep=8)
     for i in range(20):
@@ -213,14 +252,15 @@ def test_tracing_observer_effect_zero(small_cam):
 
 
 def test_traced_server_exports_valid_trace(small_cam, tmp_path):
-    srv, entry = _server(small_cam, True, sim_latency=True)
+    srv, entry = _server(small_cam, True, sim_latency=True,
+                         collect_frames=True)
     srv.attach(_poses(4), scene_id=entry.scene_id)
     srv.run(max_rounds=20)
     path = tmp_path / "serve.trace.json"
     srv.tracer.write(str(path))
     summary = validate_chrome_trace(json.loads(path.read_text()))
     for name in ("round", "plan", "dispatch", "barrier", "commit",
-                 "compile"):
+                 "compile", "fetch", "observe"):
         assert name in summary["names"]
     compiles = [ev for ev in srv.tracer.events()
                 if ev["name"] == "compile"]
@@ -230,6 +270,117 @@ def test_traced_server_exports_valid_trace(small_cam, tmp_path):
     timing = srv.cache.stats()["per_key_timing"]
     compiled = [t for t in timing.values() if t["compile_ms"] is not None]
     assert compiled and compiled[0]["dispatch_calls"] >= 1
+
+
+def _inside(child, parent):
+    return parent["ts"] <= child["ts"] and \
+        child["ts"] + child["dur"] <= parent["ts"] + parent["dur"]
+
+
+@pytest.mark.parametrize("child,parent", [
+    ("stack", "build"), ("upload", "build"), ("carry", "commit"),
+    ("fetch", "commit"), ("observe", "commit")])
+def test_traced_server_child_spans_nest_in_their_parent(small_cam, child,
+                                                        parent):
+    """Every host phase of a round under build and commit has its own
+    span, inside its parent's span on the parent's track."""
+    srv, entry = _server(small_cam, True, collect_frames=True)
+    for i in range(2):
+        srv.attach(_poses(4, dx=0.05 * i), scene_id=entry.scene_id)
+    srv.run(max_rounds=20)
+    evs = [e for e in srv.tracer.events() if e["ph"] == "X"]
+    kids = [e for e in evs if e["name"] == child]
+    parents = [e for e in evs if e["name"] == parent]
+    assert kids and parents
+    for k in kids:
+        assert any(p["tid"] == k["tid"] and _inside(k, p) for p in parents)
+    validate_chrome_trace(srv.tracer.to_chrome())
+
+
+def test_fetch_bytes_count_every_frame_copied(small_cam):
+    srv, entry = _server(small_cam, False, collect_frames=True)
+    sessions = [srv.attach(_poses(5, dx=0.05 * i), scene_id=entry.scene_id)
+                for i in range(2)]
+    report = srv.run(max_rounds=20)
+    counters = report["metrics"]["counters"]
+    assert report["frames"] == 10 == counters["serve_frames_total"]
+    assert counters["serve_fetch_bytes_total"] == \
+        10 * small_cam.height * small_cam.width * 3 * 4
+    assert counters["serve_fetch_bytes_total"] == sum(
+        f.nbytes for s in sessions for f in s.frames)
+
+
+class _FakeClock:
+    """Advances one second a read; remembers the last value read."""
+
+    def __init__(self):
+        self.now = 100.0
+
+    def __call__(self):
+        self.now += 1.0
+        return self.now
+
+
+def test_wait_seconds_sum_dispatch_less_enqueue(small_cam):
+    """Each real frame adds its group's dispatch start (the clock read
+    before the executable call) less its pose's enqueue stamp."""
+    srv, entry = _server(small_cam, False)
+    clock = srv.clock = _FakeClock()
+    stamps = {0: 3.0, 1: 7.5}
+    for i, t in stamps.items():
+        srv.attach(_poses(3 + i, dx=0.05 * i), now=t,
+                   scene_id=entry.scene_id)
+    executable = srv._executable
+    expected = []
+
+    def watched(bucket, b):
+        fn = executable(bucket, b)
+
+        def call(*args):
+            counts = np.asarray(args[2])
+            expected.extend([clock.now] * int(counts.sum()))
+            return fn(*args)
+        return call
+    srv._executable = watched
+    report = srv.run(max_rounds=20)
+    assert report["frames"] == 7 == len(expected)
+    # Poses were stamped at attach: 3 of stream 0, 4 of stream 1.
+    want = sum(expected) - 3 * stamps[0] - 4 * stamps[1]
+    assert report["metrics"]["counters"]["serve_wait_seconds_total"] == \
+        pytest.approx(want)
+
+
+@pytest.mark.parametrize("collect", [True, False])
+def test_latency_stamped_when_pixels_reach_host(small_cam, collect):
+    """With frames copied to host, a frame's latency ends after its
+    group's last ``fetch`` and within ``commit``; without, at the
+    barrier's end."""
+    srv, entry = _server(small_cam, True, collect_frames=collect)
+    t_attach = srv.clock()
+    sess = srv.attach(_poses(4), now=t_attach, scene_id=entry.scene_id)
+    srv.run(max_rounds=20)
+    origin = srv.tracer._t0_ns * 1e-9
+    evs = srv.tracer.events()
+
+    def ends(name):
+        return sorted(origin + (e["ts"] + e["dur"]) * 1e-6
+                      for e in evs if e["name"] == name)
+
+    def starts(name):
+        return sorted(origin + e["ts"] * 1e-6
+                      for e in evs if e["name"] == name)
+    lats = list(sess.latencies)
+    hist = srv.metrics.histogram("serve_latency_seconds").values()
+    assert lats == pytest.approx(hist) and len(lats) == 4
+    delivered = [t_attach + lat for lat in lats[::2]]   # chunk 2 a round
+    tol = 2e-6
+    for k, t in enumerate(delivered):
+        if collect:
+            assert t >= ends("fetch")[k] - tol
+        else:
+            assert t >= ends("barrier")[k] - tol
+            assert t <= starts("commit")[k] + tol
+        assert t <= ends("commit")[k] + tol
 
 
 def test_trace_buffer_bounded_under_serving(small_cam):

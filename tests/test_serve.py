@@ -158,7 +158,7 @@ def test_batcher_admit_build_commit(small_cam):
 
     # commit with a fake result: carries echo back, all sessions advance
     fake = SimpleNamespace(carries=batch.carries)
-    detached = bat.commit(batch, fake, m, now=1.5)
+    detached = bat.commit(batch, fake, m, now=1.5).detached
     assert [s.sid for s in detached] == [s0.sid]
     assert s0.frames_rendered == 2 and list(s0.latencies) == [1.5, 1.5]
     assert s1.frames_rendered == 3 and len(s1.pending) == 1
@@ -177,7 +177,7 @@ def test_batcher_external_detach_frees_slot(small_cam):
     batch = bat.build(m)
     m.detach(s0.sid)              # cancelled while the chunk renders
     assert bat.commit(batch, SimpleNamespace(carries=batch.carries),
-                      m, now=1.0) == []
+                      m, now=1.0).detached == []
     assert bat.bound == 0         # the slot is free again
     s1 = m.attach(np.stack([eye] * 2), now=1.0)
     assert bat.admit(m) == 1 and bat.build(m).sids == (s1.sid,)
