@@ -65,5 +65,28 @@ def cull_pairs(mask: jax.Array, slot_active: jax.Array, tile_ids: jax.Array,
               - jnp.sum(new_mask.astype(jnp.int32)))
     pre = jnp.sum(mask.astype(jnp.int32), axis=0)
     post = jnp.sum(new_mask.astype(jnp.int32), axis=0)
-    demote = (pre > 0) & (post == 0)
-    return new_mask, slot_active & ~demote, culled
+    return new_mask, demote_emptied(slot_active, pre, post), culled
+
+
+def cull_pair_list(hit: jax.Array, tile: jax.Array, prior: jax.Array,
+                   slot_active: jax.Array, tile_ids: jax.Array,
+                   gate: jax.Array, threshold: float) -> jax.Array:
+    """``cull_pairs`` on a pair list: (P,) True where a pair is culled.
+
+    hit (P,) the pairs that passed intersection; tile (P,) each pair's
+    tile id; prior (P,) its Gaussian's key-frame contribution; the other
+    arguments as in ``cull_pairs``. Pairs on tiles outside the plan's
+    active slots are never culled. Demote slots afterwards with
+    ``demote_emptied`` on the per-slot counts the binning returns.
+    """
+    gated = jnp.zeros(gate.shape, bool).at[tile_ids].set(
+        gate[tile_ids] & slot_active)              # (T,) tiles we may cull
+    return hit & ~(prior >= threshold) & gated[tile]
+
+
+def demote_emptied(slot_active: jax.Array, pre: jax.Array,
+                   post: jax.Array) -> jax.Array:
+    """Demote the slots whose pairs (``pre`` per slot) were all culled
+    (``post`` == 0): they degrade to warp/interpolation exactly like
+    plan-capacity overflow."""
+    return slot_active & ~((pre > 0) & (post == 0))

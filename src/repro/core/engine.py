@@ -160,6 +160,7 @@ def _mask_record(rec: FrameRecord, keep: jax.Array) -> FrameRecord:
         order_in_block=m(rec.order_in_block, 0),
         block_load=m(rec.block_load, 0),
         culled_pairs=m(rec.culled_pairs, 0),
+        pair_budget_overflow=m(rec.pair_budget_overflow, 0),
         lane_contrib=None if rec.lane_contrib is None
         else m(rec.lane_contrib, 0.0))
 

@@ -6,13 +6,14 @@ tile-level decisions — interpolated tiles skip preprocess/sort/raster
 entirely, re-rendered tiles go through the pipeline with DPES depth culling.
 
 Every frame renders through ONE shared stage pipeline,
-``render_planned_frame``: preprocess -> plan-masked intersect -> (R, K)
-compacted binning with DPES limits -> device-LDU schedule -> raster over
-the plan's R slots -> scatter back to the full frame. Full frames carry an
-all-tiles ``TilePlan`` (R = T); TWSR frames carry the warp-predicted
-re-render set compacted to ``R = rerender_capacity`` — so sparse-frame
-intersect/bin/sort/raster cost all scale with R instead of T (DESIGN.md
-§2). ``render_full_frame`` / ``render_sparse_frame`` are thin wrappers.
+``render_planned_frame``: preprocess -> intersect -> (R, K) compacted
+binning with DPES limits -> device-LDU schedule -> raster over the plan's
+R slots -> scatter back to the full frame. Full frames carry an all-tiles
+``TilePlan`` (R = T); TWSR frames carry the warp-predicted re-render set
+compacted to ``R = rerender_capacity`` — so sparse-frame bins and raster
+scale with R instead of T (DESIGN.md §2). TAIT's pair list covers the
+whole grid, and its one sort serves any R (DESIGN.md §3).
+``render_full_frame`` / ``render_sparse_frame`` are thin wrappers.
 
 ``render_trajectory`` (core/engine.py) is the production driver — the
 whole loop as one jitted ``lax.scan``; ``render_trajectory_py`` below is
@@ -112,6 +113,7 @@ class FrameRecord(NamedTuple):
     order_in_block: jax.Array   # (T,) int32 — light-to-heavy position
     block_load: jax.Array       # (B,) int32 — predicted pairs per block
     culled_pairs: jax.Array     # () int32 — pairs removed by culling
+    pair_budget_overflow: jax.Array  # () int32 — pairs past the pair budget
     # Per-(tile, lane) blend contribution in bin lane order (DESIGN.md
     # §12); None unless ``contrib_enabled(cfg)``.
     lane_contrib: Optional[jax.Array] = None  # (T, K) float32
@@ -124,6 +126,9 @@ class PlanStats(NamedTuple):
     raw_slots: jax.Array        # (R,) pre-DPES pairs per slot
     overflow_pairs: jax.Array   # () int32 — bin-capacity overflow
     culled_pairs: jax.Array     # () int32 — pairs removed by culling
+    # Stage-1 pairs past the pair list's budget, dropped (0 on the dense
+    # path, which has no budget).
+    pair_budget_overflow: jax.Array  # () int32
     # Per-Gaussian contribution with inf where not considered — what key
     # frames store as FrameState.contrib. None unless contrib_enabled.
     gauss_prior: Optional[jax.Array] = None  # (N,) float32
@@ -134,6 +139,86 @@ def _tile_flag_to_pixels(flag: jax.Array, tiles_x: int, tiles_y: int):
     t = flag.shape[0]
     tiles = jnp.broadcast_to(flag[:, None, None], (t, TILE, TILE))
     return untile(tiles, tiles_x, tiles_y)
+
+
+def _dense_bins(proj, grid, plan: TilePlan, cfg: RenderConfig, limit,
+                cull_prior, cull_gate):
+    """Intersect, cull and bin through the dense (N, R) mask and a
+    per-slot ``top_k``: the ablation intersect methods' path, and the
+    oracle the pair list is pinned to. Returns ``(bins, plan, stats)``."""
+    slots = intersect.take_tiles(grid, plan.tile_ids)
+    with annotate("repro.frame/intersect"):
+        if cfg.intersect_method == "tait":
+            stage1 = intersect.tait_stage1_mask(proj, slots)
+            mask = intersect.tait_mask(proj, slots)
+            cand_src = stage1
+        else:
+            mask = intersect.intersect(proj, slots, cfg.intersect_method)
+            cand_src = mask
+        candidate_pairs = jnp.sum(
+            (cand_src & plan.slot_active[None, :]).astype(jnp.int32))
+        mask = mask & plan.slot_active[None, :]
+    with annotate("repro.frame/cull"):
+        if cull_prior is not None:
+            mask, slot_active, culled_pairs = culling.cull_pairs(
+                mask, plan.slot_active, plan.tile_ids, cull_prior,
+                cull_gate, cfg.cull_threshold)
+            plan = plan._replace(slot_active=slot_active)
+        else:
+            culled_pairs = jnp.int32(0)
+        raw_slots = jnp.sum(mask.astype(jnp.int32), axis=0)
+
+    with annotate("repro.frame/bin"):
+        bins = binning.build_tile_bins(mask, proj.depth, cfg.capacity,
+                                       depth_limit=limit)
+    stats = PlanStats(candidate_pairs=candidate_pairs, raw_slots=raw_slots,
+                      overflow_pairs=jnp.sum(bins.overflow),
+                      culled_pairs=culled_pairs,
+                      pair_budget_overflow=jnp.int32(0))
+    return bins, plan, stats
+
+
+def _pair_list_bins(proj, grid, plan: TilePlan, cfg: RenderConfig, limit,
+                    cull_prior, cull_gate, *, budget: Optional[int] = None):
+    """TAIT intersect, cull and bin through a pair list sorted once
+    (DESIGN.md §3): the same bins, plan and stats as ``_dense_bins``
+    while the frame's stage-1 pairs fit the budget, T * K pair slots
+    unless ``budget`` says otherwise; pairs past it are counted."""
+    t = grid.num_tiles
+    with annotate("repro.frame/intersect"):
+        pairs = intersect.tait_pairs(
+            proj, grid, t * cfg.capacity if budget is None else budget,
+            extra=None if cull_prior is None else cull_prior[None, :])
+        # Groups: 0 binned, 1 culled (with culling on), last stage 1 only.
+        miss = 1 if cull_prior is None else 2
+        group = jnp.where(pairs.hit, 0, miss)
+    with annotate("repro.frame/cull"):
+        culled_pairs = jnp.int32(0)
+        if cull_prior is not None:
+            culled = culling.cull_pair_list(
+                pairs.hit, pairs.tile, pairs.extra[0], plan.slot_active,
+                plan.tile_ids, cull_gate, cfg.cull_threshold)
+            culled_pairs = jnp.sum(culled.astype(jnp.int32))
+            group = jnp.where(culled, 1, group)
+        group = jnp.where(pairs.stage1, group, miss + 1)
+
+    with annotate("repro.frame/bin"):
+        rank_limit = None if limit is None else jnp.searchsorted(
+            pairs.depth, limit, side="right")
+        bins, counts = binning.bin_pair_list(
+            pairs.tile, group, pairs.rank, pairs.gauss, num_groups=miss + 1,
+            num_tiles=t, num_gaussians=proj.depth.shape[0],
+            tile_ids=plan.tile_ids, slot_active=plan.slot_active,
+            capacity=cfg.capacity, rank_limit=rank_limit)
+    raw_slots = counts[:, 0]
+    if cull_prior is not None:
+        plan = plan._replace(slot_active=culling.demote_emptied(
+            plan.slot_active, raw_slots + counts[:, 1], raw_slots))
+    stats = PlanStats(candidate_pairs=jnp.sum(counts), raw_slots=raw_slots,
+                      overflow_pairs=jnp.sum(bins.overflow),
+                      culled_pairs=culled_pairs,
+                      pair_budget_overflow=pairs.dropped)
+    return bins, plan, stats
 
 
 def render_planned_frame(scene, cam: Camera, plan: TilePlan,
@@ -148,7 +233,9 @@ def render_planned_frame(scene, cam: Camera, plan: TilePlan,
     preprocess -> intersect against the plan's R slots -> contribution
     cull -> (R, K) compacted binning (with per-slot DPES depth limits) ->
     device-LDU schedule over the slots -> raster the slots -> scatter
-    back to the (H, W) frame.
+    back to the (H, W) frame. TAIT intersects and bins through a pair
+    list sorted once (``_pair_list_bins``); the other intersect methods
+    through the dense mask and ``top_k`` (``_dense_bins``).
 
     dpes_depth: optional (T,) per-tile early-stop depth (inf = no prior);
     gathered to the plan's slots before binning.
@@ -171,35 +258,18 @@ def render_planned_frame(scene, cam: Camera, plan: TilePlan,
         grid = intersect.make_tile_grid(cam)
         slots = intersect.take_tiles(grid, plan.tile_ids)
 
-    with annotate("repro.frame/intersect"):
-        if cfg.intersect_method == "tait":
-            stage1 = intersect.tait_stage1_mask(proj, slots)
-            mask = intersect.tait_mask(proj, slots)
-            cand_src = stage1
-        else:
-            mask = intersect.intersect(proj, slots, cfg.intersect_method)
-            cand_src = mask
-        candidate_pairs = jnp.sum(
-            (cand_src & plan.slot_active[None, :]).astype(jnp.int32))
-        mask = mask & plan.slot_active[None, :]
-    with annotate("repro.frame/cull"):
-        if cfg.cull_threshold > 0.0 and cull_prior is not None:
-            gate = cull_gate if cull_gate is not None \
-                else jnp.ones((cam.num_tiles,), bool)
-            mask, slot_active, culled_pairs = culling.cull_pairs(
-                mask, plan.slot_active, plan.tile_ids, cull_prior, gate,
-                cfg.cull_threshold)
-            plan = plan._replace(slot_active=slot_active)
-        else:
-            culled_pairs = jnp.int32(0)
-        raw_slots = jnp.sum(mask.astype(jnp.int32), axis=0)
-
-    with annotate("repro.frame/bin"):
-        limit = None
-        if dpes_depth is not None:
-            limit = dpes_depth[plan.tile_ids] * cfg.dpes_margin
-        bins = binning.build_tile_bins(mask, proj.depth, cfg.capacity,
-                                       depth_limit=limit)
+    limit = None
+    if dpes_depth is not None:
+        limit = dpes_depth[plan.tile_ids] * cfg.dpes_margin
+    if cfg.cull_threshold > 0.0 and cull_prior is not None:
+        if cull_gate is None:
+            cull_gate = jnp.ones((cam.num_tiles,), bool)
+    else:
+        cull_prior = cull_gate = None
+    stage = _pair_list_bins if cfg.intersect_method == "tait" \
+        else _dense_bins
+    bins, plan, stats = stage(proj, grid, plan, cfg, limit, cull_prior,
+                              cull_gate)
     # Device LDU (paper Sec. V-B): post-DPES counts are the workload
     # prediction; the greedy Morton fill + light-to-heavy order runs in
     # jnp, inside whatever jit/scan wraps this frame.
@@ -210,7 +280,6 @@ def render_planned_frame(scene, cam: Camera, plan: TilePlan,
         out = render_plan_slots(proj, bins, slots.origins, plan.tile_ids,
                                 grid, impl=cfg.impl, chunk=cfg.chunk,
                                 slot_active=plan.slot_active)
-    gauss_prior = None
     if contrib_enabled(cfg):
         # A Gaussian was "considered" if it occupies a valid bin lane
         # anywhere on the plan; everyone else gets inf (= always keep) so
@@ -218,10 +287,8 @@ def render_planned_frame(scene, cam: Camera, plan: TilePlan,
         n = proj.depth.shape[0]
         considered = jnp.zeros((n,), jnp.int32).at[bins.indices].add(
             bins.valid.astype(jnp.int32)) > 0
-        gauss_prior = jnp.where(considered, out.gauss_contrib, jnp.inf)
-    stats = PlanStats(candidate_pairs=candidate_pairs, raw_slots=raw_slots,
-                      overflow_pairs=jnp.sum(bins.overflow),
-                      culled_pairs=culled_pairs, gauss_prior=gauss_prior)
+        stats = stats._replace(gauss_prior=jnp.where(
+            considered, out.gauss_contrib, jnp.inf))
     n_gaussians = jnp.sum(proj.valid.astype(jnp.int32))
     return out, plan, n_gaussians, stats
 
@@ -248,6 +315,7 @@ def _plan_record(plan: TilePlan, stats: PlanStats, out: RenderOutput,
         order_in_block=scat(plan.order_in_block),
         block_load=plan_mod.block_loads(plan, cfg.ldu_blocks),
         culled_pairs=stats.culled_pairs,
+        pair_budget_overflow=stats.pair_budget_overflow,
         # Slot-shaped (R, K) from render_plan_slots -> (T, K) per-tile;
         # gated so the dense view only exists when the record wants it
         # (sparse compiles stay plan-shaped otherwise).

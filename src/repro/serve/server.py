@@ -330,6 +330,9 @@ class StreamServer:
         self._m_overflow_pairs = m.counter(
             "serve_overflow_pairs_total",
             "intersection pairs beyond the bin capacity K, dropped")
+        self._m_pair_budget_overflow = m.counter(
+            "serve_pair_budget_overflow_total",
+            "stage-1 pairs beyond a frame's pair budget T * K, dropped")
         self._m_demand = m.histogram(
             "device_rerender_demand",
             "re-render tiles wanted per sparse frame (pre-cap)",
@@ -667,9 +670,14 @@ class StreamServer:
         # Overflow counters over real frames and the re-render demand
         # histogram over real sparse frames (DESIGN.md §13), from the
         # records the engine already returns; the demand transfer is the
-        # one the R policy needs anyway.
+        # one the R policy needs anyway. Both pair overflows come back
+        # in one transfer.
+        past_k, past_budget = jax.device_get(
+            (recs.overflow_pairs, recs.pair_budget_overflow))
         self._m_overflow_pairs.inc(int(
-            np.asarray(recs.overflow_pairs).reshape(-1)[mask].sum()))
+            np.asarray(past_k).reshape(-1)[mask].sum()))
+        self._m_pair_budget_overflow.inc(int(
+            np.asarray(past_budget).reshape(-1)[mask].sum()))
         self._m_overflow_tiles.inc(int(
             np.asarray(recs.overflow_tiles).reshape(-1)[sparse].sum()))
         if sparse.any():

@@ -445,3 +445,25 @@ def test_frame_parity_across_chunks_with_tracing(small_cam):
     assert counters["serve_overflow_tiles_total"] == int(
         np.asarray(recs.overflow_tiles)[sparse].sum())
     assert counters["serve_overflow_tiles_total"] > 0   # R = 8 of 16 tiles
+
+
+def test_pair_budget_overflow_counter_sums_records(small_cam):
+    """With a pair budget too small for the scene (T * K = 16 * 16
+    slots), frames drop stage-1 pairs; the serve counter sums the
+    records' counts over the frames served."""
+    reg = SceneRegistry((256, 512))
+    entry = reg.register(structured_scene(jax.random.PRNGKey(9), 260,
+                                          clutter=0.4))
+    cfg = RenderConfig(window=3, capacity=16, chunk=16, rerender_capacity=8)
+    scfg = ServeConfig(slots=1, chunk=2, r_buckets=(8,),
+                       scene_buckets=(256, 512))
+    srv = StreamServer(reg, small_cam, cfg, scfg)
+    sess = srv.attach(_poses(5), scene_id=entry.scene_id)
+    report = srv.run(max_rounds=20)
+    solo = engine.render_trajectory(
+        entry.scene, small_cam, jax.numpy.asarray(_poses(5)), cfg,
+        phase=sess.phase)
+    total = int(np.asarray(solo.records.pair_budget_overflow).sum())
+    assert total > 0
+    assert report["metrics"]["counters"][
+        "serve_pair_budget_overflow_total"] == total

@@ -121,9 +121,10 @@ def _collect_shapes(jaxpr, acc):
 
 
 def test_sparse_stages_are_plan_shaped(small_scene, small_cam):
-    """The compacted sparse frame compiles with (N, R)/(R, K) intersect
-    and binning intermediates and NO dense (N, T)/(T, K) ones — the
-    wrappers really collapse onto the shared plan pipeline."""
+    """The compacted sparse frame compiles with a (T * K,) pair list and
+    (R, K) bins and NO dense (N, T) mask or (T, K) bins — the wrappers
+    really collapse onto the shared plan pipeline. The key frame bins
+    all T tiles from the same pair list, with no (N, T) mask either."""
     rcap, kcap = 4, 128
     cfg = RenderConfig(window=10, rerender_capacity=rcap, capacity=kcap)
     ref_cam, tgt_cam, state = _sparse_inputs(small_scene, small_cam, cfg)
@@ -136,7 +137,7 @@ def test_sparse_stages_are_plan_shaped(small_scene, small_cam):
         small_scene, ref_cam, tgt_cam, state)
     shapes = set()
     _collect_shapes(jx.jaxpr, shapes)
-    assert (n, rcap) in shapes, "compacted (N, R) intersect mask missing"
+    assert (t * kcap,) in shapes, "pair list missing"
     assert (rcap, kcap) in shapes, "compacted (R, K) bins missing"
     assert (n, t) not in shapes, "dense (N, T) intersect mask still built"
     assert (t, kcap) not in shapes, "dense (T, K) bins still built"
@@ -146,7 +147,8 @@ def test_sparse_stages_are_plan_shaped(small_scene, small_cam):
         functools.partial(render_full_frame, cfg=cfg))(small_scene, tgt_cam)
     full_shapes = set()
     _collect_shapes(jx_full.jaxpr, full_shapes)
-    assert (n, t) in full_shapes
+    assert (t * kcap,) in full_shapes
+    assert (n, t) not in full_shapes
     assert (t, kcap) in full_shapes
 
 
