@@ -29,6 +29,8 @@ every Gaussian whose alpha >= 1/255 ellipse has a bounding box that
 meets it, which holds every Gaussian that can reach one of its pixels,
 and blends up to ``capacity`` of them, nearest first. Pairs past that
 are counted and returned, so a run can see whether the cap mattered.
+A block of ``TILE_BLOCK`` tiles reads the Gaussians in depth order,
+``CHUNK`` at a time, so that memory does not grow with the scene.
 
 A stream's frames (``stream``) follow LS-Gaussian's schedule: a key
 frame is rendered whole, and each frame after it is made from the one
@@ -56,6 +58,7 @@ DILATION = 0.3
 FRUSTUM_MARGIN = 1.3
 CAPACITY = 2048          # Gaussians blended per tile, nearest first
 TILE_BLOCK = 1024        # tiles per block, to bound memory
+CHUNK = 65536            # Gaussians a block tests at a time
 
 SH_C0 = 0.28209479177387814
 SH_C1 = 0.4886025119029199
@@ -172,25 +175,69 @@ def project(scene: dict, w2c, cam: Intrinsics, dtype):
                 colour=colour, opacity=opacity, valid=valid, half=half)
 
 
-def _blend_block(g, rank, origins, limit, capacity: int, dtype):
-    """Blend one block of tiles, each only with the Gaussians no deeper
-    than its ``limit``. Returns ((Tb, 256, 3) rgb, then (Tb, 256) each:
-    transmittance, expected depth, truncated depth; pairs past the cap)."""
-    lo = g["uv"] - g["half"]
-    hi = g["uv"] + g["half"]
+def _meets(lo, hi, t_lo, t_hi):
+    """Whether boxes [lo, hi] meet tiles [t_lo, t_hi), broadcast."""
+    return ((lo[..., 0] < t_hi[..., 0]) & (hi[..., 0] > t_lo[..., 0])
+            & (lo[..., 1] < t_hi[..., 1]) & (hi[..., 1] > t_lo[..., 1]))
+
+
+def _select(s, n_valid, origins, limit, k: int, chunk: int):
+    """Each tile's first ``k`` Gaussians in depth order ((Tb, k) indices;
+    lanes past the count hold any index) and how many it takes in all.
+
+    ``s`` holds the Gaussians' boxes, depths and indices in depth order,
+    the ``n_valid`` valid ones first. The block keeps, in that order,
+    those that could meet one of its tiles: valid, meeting the box
+    around all its tiles, no deeper than its deepest limit. It tests
+    them ``chunk`` at a time, and a chunk's members go to each tile's
+    next free lanes. Every chunk is counted, as the pairs past the cap
+    need all of them; one whose block is full only adds to the counts.
+    """
+    tb = origins.shape[0]
     t_lo, t_hi = origins, origins + TILE
-    member = ((lo[None, :, 0] < t_hi[:, None, 0])
-              & (hi[None, :, 0] > t_lo[:, None, 0])
-              & (lo[None, :, 1] < t_hi[:, None, 1])
-              & (hi[None, :, 1] > t_lo[:, None, 1])
-              & g["valid"][None, :]
-              & (g["depth"][None, :] <= limit[:, None]))   # (Tb, N)
-    score = jnp.where(member, -rank[None, :], -jnp.inf)
-    k = min(capacity, rank.shape[0])
-    top, idx = jax.lax.top_k(score, k)                    # nearest first
-    lane_ok = jnp.isfinite(top)
-    count = jnp.sum(member, axis=1)
-    overflow = jnp.sum(jnp.maximum(count - k, 0))
+    near = ((jnp.arange(s["depth"].shape[0]) < n_valid)
+            & _meets(s["lo"], s["hi"], jnp.min(t_lo, 0), jnp.max(t_hi, 0))
+            & (s["depth"] <= jnp.max(limit)))
+    n_near = jnp.sum(near.astype(jnp.int32))
+    (keep,) = jnp.nonzero(near, size=near.shape[0], fill_value=0)
+    kc = min(k, chunk)
+    lane = jnp.arange(k)
+    first = -jnp.arange(chunk, dtype=jnp.float32)
+
+    def body(st):
+        c0, lanes, count = st
+        at = jax.lax.dynamic_slice_in_dim(keep, c0, chunk)
+        member = (_meets(s["lo"][at][None], s["hi"][at][None],
+                         t_lo[:, None], t_hi[:, None])
+                  & (c0 + jnp.arange(chunk) < n_near)[None, :]
+                  & (s["depth"][at][None, :] <= limit[:, None]))
+        got = jnp.sum(member, axis=1)
+
+        def fill(lanes):
+            _, pick = jax.lax.top_k(jnp.where(member, first, -jnp.inf), kc)
+            new = s["index"][at][pick]
+            take = jnp.take_along_axis(
+                new, jnp.clip(lane[None, :] - count[:, None], 0, kc - 1),
+                axis=1)
+            return jnp.where(lane[None, :] < count[:, None], lanes, take)
+
+        lanes = jax.lax.cond(jnp.any((count < k) & (got > 0)), fill,
+                             lambda x: x, lanes)
+        return c0 + chunk, lanes, count + got
+
+    init = (jnp.int32(0), jnp.zeros((tb, k), jnp.int32),
+            jnp.zeros((tb,), jnp.int32))
+    _, lanes, count = jax.lax.while_loop(lambda st: st[0] < n_near, body,
+                                         init)
+    return lanes, count
+
+
+def _blend(g, idx, count, origins, dtype):
+    """Blend one block of tiles, each from its lanes ``idx`` (the first
+    ``count`` of them, nearest first). Returns (Tb, 256, 3) rgb, then
+    (Tb, 256) each: transmittance, expected depth, truncated depth."""
+    k = idx.shape[1]
+    lane_ok = jnp.arange(k)[None, :] < jnp.minimum(count, k)[:, None]
     n_lanes = jnp.max(jnp.minimum(count, k))
 
     pix = np.arange(TILE * TILE)
@@ -233,30 +280,49 @@ def _blend_block(g, rank, origins, limit, capacity: int, dtype):
     _, rgb, trans, _, dacc, wacc, tdepth = jax.lax.while_loop(
         cond, body, init)
     exp_depth = dacc / jnp.maximum(wacc, 1e-8)
-    return (rgb, trans, exp_depth, tdepth), overflow
+    return rgb, trans, exp_depth, tdepth
 
 
-@functools.partial(jax.jit, static_argnames=("cam", "dtype", "capacity"))
+def _depth_sorted(g, chunk: int):
+    """The Gaussians' tile-test inputs in depth order (equal depths in
+    index order; invalid ones last), padded to a multiple of ``chunk``,
+    and how many are valid."""
+    key = jnp.where(g["valid"], g["depth"], jnp.inf).astype(jnp.float32)
+    order = jnp.argsort(key, stable=True)
+    order = jnp.pad(order, (0, -key.shape[0] % chunk))
+    s = dict(lo=(g["uv"] - g["half"])[order],
+             hi=(g["uv"] + g["half"])[order], depth=g["depth"][order],
+             index=order.astype(jnp.int32))
+    return s, jnp.sum(g["valid"].astype(jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnames=("cam", "dtype", "capacity",
+                                             "chunk"))
 def _render_tiles(scene, w2c, tile_ids, limits, cam: Intrinsics, dtype,
-                  capacity: int):
+                  capacity: int, chunk: int = CHUNK):
     """The tiles ``tile_ids`` (row-major ids; -1 pads) of the frame at
     ``w2c``, each blended only from Gaussians no deeper than its limit.
     The id count is a multiple of ``TILE_BLOCK``, or smaller than it."""
     g = project(scene, w2c, cam, dtype)
-    key = jnp.where(g["valid"], g["depth"], jnp.inf).astype(jnp.float32)
-    order = jnp.argsort(key, stable=True)
-    rank = jnp.zeros(key.shape, jnp.float32).at[order].set(
-        jnp.arange(key.shape[0], dtype=jnp.float32))
+    n = g["depth"].shape[0]
+    k, chunk = min(capacity, n), min(chunk, n)
+    s, n_valid = _depth_sorted(g, chunk)
     tx = cam.width // TILE
     origins = jnp.stack([(tile_ids % tx) * TILE, (tile_ids // tx) * TILE],
                         -1).astype(jnp.float32)
     origins = jnp.where(tile_ids[:, None] >= 0, origins, -1e6)
     m = tile_ids.shape[0]
     tb = min(TILE_BLOCK, m)
+
+    def block(a):
+        origins, limit = a
+        idx, count = _select(s, n_valid, origins, limit, k, chunk)
+        return (_blend(g, idx, count, origins, dtype),
+                jnp.sum(jnp.maximum(count - k, 0)))
+
     out, overflow = jax.lax.map(
-        lambda a: _blend_block(g, rank, a[0], a[1], capacity, dtype),
-        (origins.reshape(m // tb, tb, 2),
-         limits.astype(dtype).reshape(m // tb, tb)))
+        block, (origins.reshape(m // tb, tb, 2),
+                limits.astype(dtype).reshape(m // tb, tb)))
     rgb, trans, exp_depth, trunc_depth = (
         x.reshape((m, TILE, TILE) + x.shape[3:]).astype(jnp.float32)
         for x in out)
