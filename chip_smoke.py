@@ -13,9 +13,9 @@ checkout of the repo exits non-zero before the final line.
 
 Widths are those of ``configs/lsgaussian.py``: a 1920x1088 camera (8,160
 16-pixel tiles), K = 1024 pairs per tile, SH degree 3. The scene is a
-seeded ``structured_scene`` of 65,536 Gaussians, the top of the scene
-bucket ladder; the config's 2 M Gaussians need the sparse intersect
-(ROADMAP speed item 3) first.
+seeded ``structured_scene`` of 65,536 Gaussians, the largest scene
+bucket whose pair budget is T x K; the benchmark's ``room2m_1080p_w5``
+cell serves the config's 2 M Gaussians.
 
 The last line of standard output is one JSON object:
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.

@@ -161,6 +161,7 @@ def _mask_record(rec: FrameRecord, keep: jax.Array) -> FrameRecord:
         block_load=m(rec.block_load, 0),
         culled_pairs=m(rec.culled_pairs, 0),
         pair_budget_overflow=m(rec.pair_budget_overflow, 0),
+        stage1_pairs=m(rec.stage1_pairs, 0),
         lane_contrib=None if rec.lane_contrib is None
         else m(rec.lane_contrib, 0.0))
 

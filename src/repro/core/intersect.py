@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from repro.core import binning
 from repro.core.camera import TILE, Camera
 from repro.core.projection import ProjectedGaussians
+from repro.obs.trace import annotate
 
 # Circumcircle radius of a 16x16 tile (r in eq. 7).
 TILE_CIRCUMRADIUS = float(TILE) * (2.0 ** 0.5) / 2.0
@@ -172,6 +173,7 @@ class TilePairs(NamedTuple):
     extra: jax.Array    # (E, P) float32 the caller's per-Gaussian rows
     depth: jax.Array    # (N,) float32 depths in ascending (rank) order
     dropped: jax.Array  # () int32 stage-1 pairs past the budget
+    total: jax.Array    # () int32 stage-1 pairs, the budget's demand
 
 
 def _spread(rows: jax.Array, start: jax.Array, budget: int) -> jax.Array:
@@ -206,7 +208,8 @@ def tait_pairs(proj: ProjectedGaussians, grid: TileGrid, budget: int,
     run of slots, row-major, at the exclusive running sum of rectangle
     areas in Gaussian order, and stage 2 is tested per pair. Rectangles
     past the budget lose their tail pairs, the highest-indexed Gaussians'
-    first, counted in ``dropped`` (saturating at ``_COUNT_CAP``). Each
+    first, counted in ``dropped``; ``total`` counts every stage-1 pair
+    (both saturating at ``_COUNT_CAP``). Each
     pair carries its Gaussian's rank in ascending depth, ties to the
     lower index: the order ``top_k`` ranks in. ``extra`` (E, N),
     optional, holds float rows the caller wants per pair
@@ -218,10 +221,11 @@ def tait_pairs(proj: ProjectedGaussians, grid: TileGrid, budget: int,
     if grid.num_tiles * (tx + 1) >= 2 ** 31:
         raise ValueError(f"{grid.num_tiles} tiles are too many to pack")
     n = proj.depth.shape[0]
-    _, order = binning.sort_1d(
-        (proj.depth, jnp.arange(n, dtype=jnp.int32)), is_stable=True)
-    rank = jnp.zeros((n,), jnp.int32).at[order].set(
-        jnp.arange(n, dtype=jnp.int32))
+    with annotate("repro.frame/rank"):
+        _, order = binning.sort_1d(
+            (proj.depth, jnp.arange(n, dtype=jnp.int32)), is_stable=True)
+        rank = jnp.zeros((n,), jnp.int32).at[order].set(
+            jnp.arange(n, dtype=jnp.int32))
     first, extent = tile_rects(proj.mean2d, proj.tight_half_wh, proj.valid,
                                tx, grid.tiles_y)
     area = extent[:, 0] * extent[:, 1]
@@ -255,7 +259,8 @@ def tait_pairs(proj: ProjectedGaussians, grid: TileGrid, budget: int,
         hit=stage1 & keep,
         extra=jnp.stack([_floats(v) for v in extra_rows]) if extra_rows
         else jnp.zeros((0, budget), jnp.float32),
-        depth=proj.depth[order], dropped=jnp.maximum(end[-1] - budget, 0))
+        depth=proj.depth[order], dropped=jnp.maximum(end[-1] - budget, 0),
+        total=end[-1])
 
 
 def obb_mask(proj: ProjectedGaussians, grid: TileGrid) -> jax.Array:

@@ -114,6 +114,7 @@ class FrameRecord(NamedTuple):
     block_load: jax.Array       # (B,) int32 — predicted pairs per block
     culled_pairs: jax.Array     # () int32 — pairs removed by culling
     pair_budget_overflow: jax.Array  # () int32 — pairs past the pair budget
+    stage1_pairs: jax.Array     # () int32 — stage-1 pairs on the whole grid
     # Per-(tile, lane) blend contribution in bin lane order (DESIGN.md
     # §12); None unless ``contrib_enabled(cfg)``.
     lane_contrib: Optional[jax.Array] = None  # (T, K) float32
@@ -129,6 +130,9 @@ class PlanStats(NamedTuple):
     # Stage-1 pairs past the pair list's budget, dropped (0 on the dense
     # path, which has no budget).
     pair_budget_overflow: jax.Array  # () int32
+    # Stage-1 (bbox) pairs over the whole grid, whatever the plan: what
+    # the pair list has to hold, before its budget.
+    stage1_pairs: jax.Array  # () int32
     # Per-Gaussian contribution with inf where not considered — what key
     # frames store as FrameState.contrib. None unless contrib_enabled.
     gauss_prior: Optional[jax.Array] = None  # (N,) float32
@@ -139,6 +143,37 @@ def _tile_flag_to_pixels(flag: jax.Array, tiles_x: int, tiles_y: int):
     t = flag.shape[0]
     tiles = jnp.broadcast_to(flag[:, None, None], (t, TILE, TILE))
     return untile(tiles, tiles_x, tiles_y)
+
+
+# Scenes of more Gaussians than this get a pair budget of at least
+# PAIRS_PER_GAUSSIAN slots a Gaussian (DESIGN.md §3).
+PAIR_BUDGET_MIN_N = 2 ** 16
+PAIRS_PER_GAUSSIAN = 8
+
+
+def pair_budget(num_tiles: int, capacity: int, num_gaussians: int) -> int:
+    """Pair slots of a frame's TAIT pair list: T * K, raised to
+    ``PAIRS_PER_GAUSSIAN * N`` for scenes above ``PAIR_BUDGET_MIN_N``.
+
+    N is the scene's row count, its bucket's padded N when served, so
+    the budget is fixed per executable. At 1080p (T * K = 8,355,840 at
+    K = 1,024) every bucket up to 65,536 keeps T * K; 2**21 rows get
+    2**24 slots, for the ~10 M stage-1 pairs of a 2,000,000-Gaussian
+    room.
+    """
+    budget = num_tiles * capacity
+    if num_gaussians > PAIR_BUDGET_MIN_N:
+        budget = max(budget, PAIRS_PER_GAUSSIAN * num_gaussians)
+    return budget
+
+
+def _stage1_total(proj, grid) -> jax.Array:
+    """Stage-1 pairs over the whole grid: the area of each Gaussian's
+    tile rectangle, summed (the count ``tait_pairs`` reports as
+    ``total``)."""
+    _, extent = intersect.tile_rects(proj.mean2d, proj.tight_half_wh,
+                                     proj.valid, grid.tiles_x, grid.tiles_y)
+    return jnp.sum(extent[:, 0] * extent[:, 1])
 
 
 def _dense_bins(proj, grid, plan: TilePlan, cfg: RenderConfig, limit,
@@ -158,6 +193,7 @@ def _dense_bins(proj, grid, plan: TilePlan, cfg: RenderConfig, limit,
         candidate_pairs = jnp.sum(
             (cand_src & plan.slot_active[None, :]).astype(jnp.int32))
         mask = mask & plan.slot_active[None, :]
+        stage1_pairs = _stage1_total(proj, grid)
     with annotate("repro.frame/cull"):
         if cull_prior is not None:
             mask, slot_active, culled_pairs = culling.cull_pairs(
@@ -174,7 +210,8 @@ def _dense_bins(proj, grid, plan: TilePlan, cfg: RenderConfig, limit,
     stats = PlanStats(candidate_pairs=candidate_pairs, raw_slots=raw_slots,
                       overflow_pairs=jnp.sum(bins.overflow),
                       culled_pairs=culled_pairs,
-                      pair_budget_overflow=jnp.int32(0))
+                      pair_budget_overflow=jnp.int32(0),
+                      stage1_pairs=stage1_pairs)
     return bins, plan, stats
 
 
@@ -182,12 +219,14 @@ def _pair_list_bins(proj, grid, plan: TilePlan, cfg: RenderConfig, limit,
                     cull_prior, cull_gate, *, budget: Optional[int] = None):
     """TAIT intersect, cull and bin through a pair list sorted once
     (DESIGN.md §3): the same bins, plan and stats as ``_dense_bins``
-    while the frame's stage-1 pairs fit the budget, T * K pair slots
+    while the frame's stage-1 pairs fit the budget, ``pair_budget``'s
     unless ``budget`` says otherwise; pairs past it are counted."""
     t = grid.num_tiles
+    if budget is None:
+        budget = pair_budget(t, cfg.capacity, proj.depth.shape[0])
     with annotate("repro.frame/intersect"):
         pairs = intersect.tait_pairs(
-            proj, grid, t * cfg.capacity if budget is None else budget,
+            proj, grid, budget,
             extra=None if cull_prior is None else cull_prior[None, :])
         # Groups: 0 binned, 1 culled (with culling on), last stage 1 only.
         miss = 1 if cull_prior is None else 2
@@ -217,7 +256,8 @@ def _pair_list_bins(proj, grid, plan: TilePlan, cfg: RenderConfig, limit,
     stats = PlanStats(candidate_pairs=jnp.sum(counts), raw_slots=raw_slots,
                       overflow_pairs=jnp.sum(bins.overflow),
                       culled_pairs=culled_pairs,
-                      pair_budget_overflow=pairs.dropped)
+                      pair_budget_overflow=pairs.dropped,
+                      stage1_pairs=pairs.total)
     return bins, plan, stats
 
 
@@ -316,6 +356,7 @@ def _plan_record(plan: TilePlan, stats: PlanStats, out: RenderOutput,
         block_load=plan_mod.block_loads(plan, cfg.ldu_blocks),
         culled_pairs=stats.culled_pairs,
         pair_budget_overflow=stats.pair_budget_overflow,
+        stage1_pairs=stats.stage1_pairs,
         # Slot-shaped (R, K) from render_plan_slots -> (T, K) per-tile;
         # gated so the dense view only exists when the record wants it
         # (sparse compiles stay plan-shaped otherwise).

@@ -38,9 +38,9 @@ from repro.serve.cache import validate_buckets
 # Pow-2 ladder: padding waste is bounded by 2x, and the distinct-
 # executable family is bounded by the handful of bucket sizes a fleet's
 # scenes actually span (each bucket in use is one more compile per
-# (B, R) key — see server._key_for).
-DEFAULT_SCENE_BUCKETS = (256, 512, 1024, 2048, 4096, 8192, 16384,
-                         32768, 65536)
+# (B, R) key — see server._key_for). It reaches 2**21, which holds the
+# 2,000,000 Gaussians of the paper's scenes.
+DEFAULT_SCENE_BUCKETS = tuple(2 ** e for e in range(8, 22))
 
 # sigmoid(-20) ~ 2e-9, far below projection.ALPHA_THRESHOLD (1/255):
 # padding Gaussians fail the `visible` cull for every pose.

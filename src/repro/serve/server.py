@@ -332,7 +332,10 @@ class StreamServer:
             "intersection pairs beyond the bin capacity K, dropped")
         self._m_pair_budget_overflow = m.counter(
             "serve_pair_budget_overflow_total",
-            "stage-1 pairs beyond a frame's pair budget T * K, dropped")
+            "stage-1 pairs beyond a frame's pair budget, dropped")
+        self._m_stage1_pairs = m.counter(
+            "serve_stage1_pairs_total",
+            "stage-1 pairs of the frames served, before the pair budget")
         self._m_demand = m.histogram(
             "device_rerender_demand",
             "re-render tiles wanted per sparse frame (pre-cap)",
@@ -670,14 +673,17 @@ class StreamServer:
         # Overflow counters over real frames and the re-render demand
         # histogram over real sparse frames (DESIGN.md §13), from the
         # records the engine already returns; the demand transfer is the
-        # one the R policy needs anyway. Both pair overflows come back
-        # in one transfer.
-        past_k, past_budget = jax.device_get(
-            (recs.overflow_pairs, recs.pair_budget_overflow))
+        # one the R policy needs anyway. Both pair overflows and the
+        # stage-1 pair counts come back in one transfer.
+        past_k, past_budget, stage1 = jax.device_get(
+            (recs.overflow_pairs, recs.pair_budget_overflow,
+             recs.stage1_pairs))
         self._m_overflow_pairs.inc(int(
             np.asarray(past_k).reshape(-1)[mask].sum()))
         self._m_pair_budget_overflow.inc(int(
             np.asarray(past_budget).reshape(-1)[mask].sum()))
+        self._m_stage1_pairs.inc(int(
+            np.asarray(stage1).reshape(-1)[mask].sum()))
         self._m_overflow_tiles.inc(int(
             np.asarray(recs.overflow_tiles).reshape(-1)[sparse].sum()))
         if sparse.any():

@@ -467,3 +467,41 @@ def test_pair_budget_overflow_counter_sums_records(small_cam):
     assert total > 0
     assert report["metrics"]["counters"][
         "serve_pair_budget_overflow_total"] == total
+
+
+def test_stage1_pairs_counter_rides_the_overflow_transfer(small_cam,
+                                                          monkeypatch):
+    """``serve_stage1_pairs_total`` sums the records' stage-1 pair counts
+    over the frames served, and comes back in the transfer that already
+    carries both pair overflows: one ``device_get`` of three arrays per
+    group observed, as before the counter was added, with two."""
+    srv, entry = _server(small_cam, False)
+    sess = srv.attach(_poses(5), scene_id=entry.scene_id)
+    real_get, observe = jax.device_get, srv._observe
+    inside, gets, groups = [], [], []
+
+    def counted(tree):
+        if inside:
+            gets.append(len(jax.tree_util.tree_leaves(tree)))
+        return real_get(tree)
+
+    def observed(result):
+        inside.append(True)
+        try:
+            observe(result)
+        finally:
+            inside.pop()
+        groups.append(result)
+
+    monkeypatch.setattr(jax, "device_get", counted)
+    srv._observe = observed
+    report = srv.run(max_rounds=20)
+    monkeypatch.undo()
+    solo = engine.render_trajectory(
+        entry.scene, small_cam, jax.numpy.asarray(_poses(5)),
+        RenderConfig(window=3, capacity=128, rerender_capacity=8),
+        phase=sess.phase)
+    total = int(np.asarray(solo.records.stage1_pairs).sum())
+    assert total > 0
+    assert report["metrics"]["counters"]["serve_stage1_pairs_total"] == total
+    assert groups and gets == [3] * len(groups)

@@ -11,20 +11,32 @@ builds the (N, R) mask and runs ``binning.build_tile_bins``. Pinned:
   - a budget smaller than the frame's stage-1 pairs drops the
     highest-indexed Gaussians' tail pairs, and ``pair_budget_overflow``
     counts them;
+  - the budget ``pipeline.pair_budget`` derives: T * K for every scene
+    bucket up to 65,536 (the same 1080p jaxpr as with T * K given),
+    more for larger scenes, where a frame with more stage-1 pairs than
+    T * K still bins like the oracle; the stage-1 count equals the
+    dense mask's;
   - frames and records of a short trajectory are bit-equal to the dense
     path through the scanned engine, on ``jnp_chunked`` and the fused
     kernel in interpret mode.
 """
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import binning, intersect, pipeline, plan as plan_mod
+from repro.core.camera import look_at, make_camera
 from repro.core.engine import render_trajectory
+from repro.core.gaussians import GaussianScene
 from repro.core.pipeline import RenderConfig
 from repro.core.projection import preprocess
+from repro.scenes.synthetic import structured_scene
 from repro.scenes.trajectory import dolly_trajectory
+from repro.serve.scenes import DEFAULT_SCENE_BUCKETS
 
 
 def _inputs(scene, cam, case):
@@ -76,7 +88,7 @@ def _assert_same(got, ref):
     np.testing.assert_array_equal(np.asarray(plan.slot_active),
                                   np.asarray(rplan.slot_active))
     for name in ("candidate_pairs", "raw_slots", "overflow_pairs",
-                 "culled_pairs"):
+                 "culled_pairs", "stage1_pairs"):
         np.testing.assert_array_equal(np.asarray(getattr(stats, name)),
                                       np.asarray(getattr(rstats, name)),
                                       err_msg=name)
@@ -172,3 +184,74 @@ def test_frames_match_dense_path(small_scene, small_cam, impl, monkeypatch):
                                       err_msg=field)
     assert not bool(np.asarray(got.records.is_full).all())
     assert not np.asarray(got.records.pair_budget_overflow).any()
+
+
+def test_pair_budget_is_t_by_k_up_to_65536():
+    """At 1080p and K = 1,024 every scene bucket up to 65,536 keeps the
+    budget T * K; above, the budget is 8 slots a padded row where that
+    is more, and small grids keep T * K at every size up to 65,536."""
+    t, k = 120 * 68, 1024
+    for n in DEFAULT_SCENE_BUCKETS:
+        want = t * k if n <= 2 ** 16 else max(t * k, 8 * n)
+        assert pipeline.pair_budget(t, k, n) == want
+    assert pipeline.pair_budget(t, k, 65_536) == 8_355_840
+    assert pipeline.pair_budget(t, k, 2 ** 20) == 8_388_608
+    assert pipeline.pair_budget(t, k, 2 ** 21) == 2 ** 24
+    assert pipeline.pair_budget(16, 16, 512) == 256
+    assert pipeline.pair_budget(48, 32, 2 ** 16) == 48 * 32
+    assert pipeline.pair_budget(48, 32, 70_000) == 560_000
+
+
+def _jaxpr_1080p(n):
+    """The jaxpr of a 1080p key frame of ``n`` Gaussians (shapes only),
+    without the addresses of the Python functions it names."""
+    cam = make_camera(look_at((0.0, -0.3, -2.0), (0.0, 0.0, 6.0)),
+                      width=1920, height=1088)
+    cfg = RenderConfig(capacity=1024, chunk=64, impl="jnp_chunked")
+    plan = plan_mod.full_plan(cam.tiles_x, cam.tiles_y)
+    scene = GaussianScene(*(jax.ShapeDtypeStruct(s, jnp.float32) for s in (
+        (n, 3), (n, 3), (n, 4), (n,), (n, 16, 3))))
+    text = str(jax.make_jaxpr(lambda sc: pipeline.render_planned_frame(
+        sc, cam, plan, cfg))(scene))
+    return re.sub(r" at 0x[0-9a-f]+", "", text)
+
+
+@pytest.mark.parametrize("n,slots", [(65_536, 8_355_840),
+                                     (2 ** 21, 2 ** 24)])
+def test_1080p_pair_list_takes_the_derived_budget(n, slots, monkeypatch):
+    """The pair list of a 1080p frame has the derived number of slots;
+    at the 65,536 bucket the frame traces to the same jaxpr as with the
+    budget T * K given, as before the budget was derived."""
+    got = _jaxpr_1080p(n)
+    assert f"i32[{slots}]" in got
+    if n <= 2 ** 16:
+        monkeypatch.setattr(pipeline, "_pair_list_bins", functools.partial(
+            pipeline._pair_list_bins, budget=8160 * 1024))
+        assert _jaxpr_1080p(n) == got
+
+
+@pytest.fixture(scope="module")
+def large_scene():
+    """More Gaussians than 2**16: the derived budget exceeds T * K."""
+    return structured_scene(jax.random.PRNGKey(21), 70_000, clutter=0.5)
+
+
+@pytest.mark.parametrize("case", ["full", "sparse"])
+def test_derived_budget_holds_pairs_past_t_by_k(large_scene, wide_cam,
+                                                case):
+    """A small-grid frame of 70,000 Gaussians has more stage-1 pairs
+    than T * K but fewer than the derived budget: nothing is dropped,
+    the bins equal the oracle's, and the stage-1 count is the dense
+    mask's over the whole grid."""
+    args = _inputs(large_scene, wide_cam, case)
+    proj, grid = args[:2]
+    cfg = RenderConfig(capacity=32)
+    stage1 = int(np.asarray(intersect.tait_stage1_mask(proj, grid)).sum())
+    assert grid.num_tiles * cfg.capacity < stage1 <= pipeline.pair_budget(
+        grid.num_tiles, cfg.capacity, proj.depth.shape[0])
+    got = _stage(pipeline._pair_list_bins, cfg, *args)
+    _assert_same(got, _stage(pipeline._dense_bins, cfg, *args))
+    stats = got[2]
+    assert int(stats.pair_budget_overflow) == 0
+    assert int(stats.stage1_pairs) == stage1
+    assert int(stats.overflow_pairs) > 0

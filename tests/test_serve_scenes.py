@@ -1,7 +1,8 @@
 """Multi-scene serving (DESIGN.md §10): scene-bucket padding parity, the
 registry lifecycle, the (B, R) bucket policy, scene-aware slot packing,
-the engine's slot_scene gather vs solo renders, and end-to-end server
-parity across scene mixing, chunk seams, and an elastic-B resize."""
+the engine's slot_scene gather vs solo renders, end-to-end server
+parity across scene mixing, chunk seams, and an elastic-B resize, and a
+scene above 65,536 Gaussians served against the program's oracles."""
 import json
 import os
 import subprocess
@@ -13,14 +14,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import engine
+from repro.core import binning, engine, intersect, pipeline, raster
+from repro.core.camera import make_camera
 from repro.core.pipeline import RenderConfig, render_full_frame
+from repro.core.projection import preprocess
 from repro.scenes.synthetic import random_blob_scene, structured_scene
 from repro.scenes.trajectory import dolly_trajectory
 from repro.serve import (AdmissionConfig, BucketPolicy, ContinuousBatcher,
                          SceneRegistry, ServeConfig, SessionManager,
                          StreamServer, pad_scene, snap_scene_bucket,
                          suggest_buckets)
+from repro.serve.scenes import DEFAULT_SCENE_BUCKETS
 
 _RECORD_FIELDS = ("is_full", "n_gaussians", "candidate_pairs", "raw_pairs",
                   "sort_pairs", "raster_pairs", "active",
@@ -462,3 +466,101 @@ def test_server_skew_starvation_bounded_wait(small_cam):
             cfg, phase=sess.phase)
         np.testing.assert_allclose(got, np.asarray(solo.frames),
                                    atol=1e-5)
+
+
+# --- scenes above 65,536 Gaussians ----------------------------------------
+
+def test_default_ladder_reaches_the_paper_scene():
+    """The default ladder runs in powers of two to 2**21, which holds
+    the 2,000,000 Gaussians of the paper's scenes."""
+    assert DEFAULT_SCENE_BUCKETS == tuple(2 ** e for e in range(8, 22))
+    assert snap_scene_bucket(65_536) == 65_536
+    assert snap_scene_bucket(70_000) == 131_072
+    assert snap_scene_bucket(2_000_000) == 2_097_152
+    with pytest.raises(ValueError):
+        snap_scene_bucket(2 ** 21 + 1)
+
+
+@pytest.fixture(scope="module")
+def scene70k():
+    return structured_scene(jax.random.PRNGKey(70), 70_000, clutter=0.5)
+
+
+def _cam128x64():
+    return make_camera(np.asarray(_poses(1)[0]), width=128, height=64)
+
+
+def _large_cfg():
+    return RenderConfig(window=5, capacity=64, rerender_capacity=16)
+
+
+def test_large_scene_padding_renders_bit_identical(scene70k):
+    """70,000 Gaussians register in the 131,072 bucket. A key frame and
+    the four sparse frames after it render the padded scene as the
+    unpadded one, frames and every record field bit for bit, though the
+    two trace with different pair budgets (1,048,576 and 560,000)."""
+    entry = SceneRegistry().register(scene70k)
+    assert entry.bucket == (131_072, 4) and entry.true_n == 70_000
+    cam, cfg = _cam128x64(), _large_cfg()
+    poses = jnp.asarray(_poses(5))
+    got = engine.render_trajectory(entry.scene, cam, poses, cfg)
+    ref = engine.render_trajectory(scene70k, cam, poses, cfg)
+    np.testing.assert_array_equal(np.asarray(got.frames),
+                                  np.asarray(ref.frames))
+    for name in pipeline.FrameRecord._fields:
+        a, b = getattr(got.records, name), getattr(ref.records, name)
+        if a is None:
+            assert b is None
+            continue
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+    assert int(np.asarray(got.records.is_full).sum()) == 1
+    assert int(np.asarray(got.records.stage1_pairs)[0]) > \
+        cam.num_tiles * cfg.capacity
+
+
+def test_large_scene_served_frames_match_oracles(scene70k, monkeypatch):
+    """A window of 5 frames of the 70,000-Gaussian scene served through
+    ``StreamServer`` equals the same frames binned by the dense ``top_k``
+    oracle (``_dense_bins``); its key frame equals the dense mask's bins
+    drawn by the plain raster (``render_from_bins``, ``kernels/ref.py``);
+    and the serve counters hold
+    the oracle's pair counts, the stage-1 ones those of the dense mask
+    over the whole grid, none past the pair budget."""
+    reg = SceneRegistry()
+    entry = reg.register(scene70k)
+    cam, cfg = _cam128x64(), _large_cfg()
+    poses = np.asarray(_poses(5))
+    srv = StreamServer(reg, cam, cfg, ServeConfig(
+        slots=1, chunk=5, r_buckets=(16,), collect_frames=True))
+    sess = srv.attach(poses, scene_id=entry.scene_id)
+    counters = srv.run(max_rounds=10)["metrics"]["counters"]
+    got = np.concatenate(sess.frames)
+    assert got.shape[0] == 5
+
+    monkeypatch.setattr(pipeline, "_pair_list_bins", pipeline._dense_bins)
+    jax.clear_caches()      # retrace: the engine's jit is keyed on cfg
+    dense = engine.render_trajectory(entry.scene, cam, jnp.asarray(poses),
+                                     cfg, phase=sess.phase)
+    monkeypatch.undo()
+    jax.clear_caches()
+    np.testing.assert_allclose(got, np.asarray(dense.frames), atol=1e-5)
+    recs = dense.records
+    full = np.asarray(recs.is_full)
+    assert full[0] and not full.all()
+
+    grid = intersect.make_tile_grid(cam)
+    stage1 = [int(np.asarray(intersect.tait_stage1_mask(
+        preprocess(entry.scene, cam.with_pose(jnp.asarray(p))), grid)).sum())
+        for p in poses]
+    np.testing.assert_array_equal(np.asarray(recs.stage1_pairs), stage1)
+    assert counters["serve_stage1_pairs_total"] == sum(stage1)
+    assert counters["serve_pair_budget_overflow_total"] == 0
+    assert counters["serve_overflow_pairs_total"] == int(
+        np.asarray(recs.overflow_pairs).sum()) > 0
+
+    proj = preprocess(entry.scene, cam.with_pose(jnp.asarray(poses[0])))
+    bins = binning.build_tile_bins(intersect.tait_mask(proj, grid),
+                                   proj.depth, cfg.capacity)
+    key = raster.render_from_bins(proj, bins, grid, impl="ref")
+    np.testing.assert_allclose(got[0], np.asarray(key.rgb), atol=2e-5)
