@@ -10,22 +10,16 @@ Configurations:
                 blocks, raw workloads           (Fig. 14 "GSCore")
   +LD1        : + LDU inter-block balancing on DPES predictions
   +LD2 (full) : + light-to-heavy intra-block order (LS-Gaussian)
-  recorded    : the device-LDU schedule the jitted engine recorded in
-                each FrameRecord, served as-is
-                (``simulate_sequence(policy="recorded")``) — vs the
-                host re-derivation of "ls_gaussian". The jnp LDU is
-                pinned bit-identical to the numpy reference
-                (tests/test_load_balance.py), so the emitted deltas
-                must be ~0; a drift here means the on-device schedule
-                no longer matches the host ablations.
+
+Every mode schedules on the host (numpy ``load_balance.schedule``) from
+the records' workloads and re-render sets; the renderer computes no
+schedule of its own.
 
 Table I = raster-core utilization of gscore_like vs full LS-Gaussian.
 """
 from __future__ import annotations
 
 from typing import List
-
-import numpy as np
 
 from benchmarks.common import camera, scenes, trajectory
 from repro.core.pipeline import RenderConfig, render_trajectory
@@ -63,14 +57,11 @@ def run() -> List[dict]:
                                          cam.tiles_y,
                                          cam.width * cam.height)
         base_cycles = None
-        host = None
         for mode, kw in MODES.items():
             t = throughput(simulate_sequence(frames, acfg, **kw),
                            acfg.num_blocks)
             if base_cycles is None:
                 base_cycles = t["cycles_per_frame"]
-            if mode == "ls_gaussian":
-                host = t
             rows.append({
                 "bench": "fig14_15_accelerator", "scene": scene_name,
                 "mode": mode,
@@ -80,23 +71,4 @@ def run() -> List[dict]:
                 "utilization_pct": round(100 * t["utilization"], 1),
                 "sort_stall": int(t["sort_stall"]),
             })
-        # Recorded-vs-host: serve the engine's own device-LDU schedule and
-        # report the delta against the host-side "ls_gaussian" derivation.
-        rec = throughput(
-            simulate_sequence(frames, acfg, policy="recorded"),
-            acfg.num_blocks)
-        rows.append({
-            "bench": "fig14_15_accelerator", "scene": scene_name,
-            "mode": "recorded",
-            "cycles_per_frame": int(rec["cycles_per_frame"]),
-            "speedup_vs_gpu_like": round(
-                base_cycles / rec["cycles_per_frame"], 2),
-            "utilization_pct": round(100 * rec["utilization"], 1),
-            "sort_stall": int(rec["sort_stall"]),
-            "cycles_delta_vs_host_pct": round(
-                100.0 * (rec["cycles_per_frame"] - host["cycles_per_frame"])
-                / host["cycles_per_frame"], 4),
-            "utilization_delta_vs_host_pct": round(
-                100.0 * (rec["utilization"] - host["utilization"]), 4),
-        })
     return rows

@@ -10,8 +10,8 @@ whole run must stay within one compilation per
 ``(scene_bucket, B, R)`` key — that is the recompilation bound the
 bucketing buys, now across scenes AND batch sizes), and the simulated
 ASIC latency of the served frames through the paper's accelerator model
-(``core/streaming.py``, recorded-schedule policy) next to the
-wall-clock numbers.
+(``core/streaming.py``, the paper's LDU scheduled on the host) next to
+the wall-clock numbers.
 
 Writes ``experiments/artifacts/serve_bench.json`` (full report +
 per-round trace) and returns summary rows for ``benchmarks/run.py``.

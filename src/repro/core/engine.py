@@ -9,11 +9,11 @@ single ``lax.scan`` so an entire trajectory compiles ONCE and runs with
 no host involvement, and ``jax.vmap``s that scan over a leading stream
 axis for batched multi-user serving. Both frame branches are thin
 wrappers over the plan-driven ``pipeline.render_planned_frame`` — the
-TilePlan construction AND the device-LDU schedule it records run inside
-this scan (DESIGN.md §2), and both branches raster through
-``RenderConfig.impl`` (DESIGN.md §9: the fused plan-slot Pallas kernel
-on TPU backends by default), so every stream and the serve loop inherit
-the kernel selection with no engine-level switches.
+TilePlan construction runs inside this scan (DESIGN.md §2), and both
+branches raster through ``RenderConfig.impl`` (DESIGN.md §9: the fused
+plan-slot Pallas kernel on TPU backends by default), so every stream and
+the serve loop inherit the kernel selection with no engine-level
+switches.
 
 Scan carry layout (``EngineCarry``):
 
@@ -142,7 +142,7 @@ def init_stream_carries(cam: Camera, poses_batch: jax.Array,
 
 def _mask_record(rec: FrameRecord, keep: jax.Array) -> FrameRecord:
     """Blank an inactive (padding) frame's record: zero counts, no active
-    tiles, unscheduled LDU blocks — so masked frames read as no work."""
+    tiles — so masked frames read as no work."""
     def m(v, blank):
         return jnp.where(keep, v, jnp.asarray(blank, v.dtype))
     return FrameRecord(
@@ -156,9 +156,6 @@ def _mask_record(rec: FrameRecord, keep: jax.Array) -> FrameRecord:
         tiles_interpolated=m(rec.tiles_interpolated, 0),
         overflow_pairs=m(rec.overflow_pairs, 0),
         overflow_tiles=m(rec.overflow_tiles, 0),
-        block_of_tile=m(rec.block_of_tile, -1),
-        order_in_block=m(rec.order_in_block, 0),
-        block_load=m(rec.block_load, 0),
         culled_pairs=m(rec.culled_pairs, 0),
         pair_budget_overflow=m(rec.pair_budget_overflow, 0),
         stage1_pairs=m(rec.stage1_pairs, 0),

@@ -16,22 +16,16 @@ Baselines: "static_blocked" (contiguous raster-order chunks),
 "round_robin" (tile i -> block i mod B), "dynamic" (greedy
 shortest-queue, models the GPU hardware scheduler).
 
-Two implementations live side by side:
-
-- ``schedule`` (numpy, host-side): the original, straightforwardly
-  auditable version — kept as the golden reference and used by the
-  accelerator simulator's host-side ablations (core/streaming.py).
-- ``ldu_schedule`` / ``greedy_fill`` / ``order_within_blocks`` (jnp,
-  device-side): the jit-compatible port the plan-driven renderer calls
-  *inside* the scanned streaming loop (core/plan.py, core/pipeline.py),
-  so every ``FrameRecord`` carries the LDU block assignment with no host
-  callback. ``tests/test_load_balance.py`` pins the two implementations
-  to bit-identical block assignments across all four policies.
+``schedule`` (numpy, host-side) is the one LDU: the accelerator
+simulator (core/streaming.py) builds each frame's schedule with it from
+the recorded DPES workloads (``FrameRecord.sort_pairs``) and re-render
+set (``active``). Nothing on the chip reads a schedule — the raster grid
+visits plan slots in Morton order — so the renderer computes none.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,12 +34,12 @@ import numpy as np
 
 @dataclasses.dataclass(frozen=True)
 class Schedule:
-    block_of_tile: np.ndarray   # (T,) block id per tile (-1 = not scheduled)
+    block_of: np.ndarray        # (T,) block id per tile (-1 = not scheduled)
     order_in_block: np.ndarray  # (T,) execution position within its block
     num_blocks: int
 
     def tiles_of_block(self, b: int) -> np.ndarray:
-        ids = np.where(self.block_of_tile == b)[0]
+        ids = np.where(self.block_of == b)[0]
         return ids[np.argsort(self.order_in_block[ids], kind="stable")]
 
 
@@ -147,12 +141,9 @@ def schedule(workload: np.ndarray, num_blocks: int, *,
     return Schedule(block_of, order_in, b)
 
 
-# --------------------------------------------------------------------------
-# Device-side (jnp) port — runs inside the jitted lax.scan streaming loop.
-# --------------------------------------------------------------------------
-
 def morton_rank(tiles_x: int, tiles_y: int) -> jax.Array:
-    """(T,) Z-order visit priority per tile id (jnp; constant under jit).
+    """(T,) Z-order visit priority per tile id (jnp; constant under jit):
+    the order of a ``TilePlan``'s slots (core/plan.py).
 
     ``rank[tid]`` is the position of tile ``tid`` along the Morton curve,
     so ``jnp.argsort(rank)`` equals the numpy ``morton_order`` traversal.
@@ -174,128 +165,11 @@ def morton_rank(tiles_x: int, tiles_y: int) -> jax.Array:
         jnp.arange(t, dtype=jnp.int32))
 
 
-def greedy_fill(workload: jax.Array, active: jax.Array,
-                num_blocks: int) -> jax.Array:
-    """Paper's greedy capacity fill over slots IN ORDER (device scan).
-
-    Callers present slots in the intended traversal order (Morton for the
-    plan path). A slot joins the current block unless that would push the
-    block past ``(1 + 1/N) * W``; it then defers cyclically to the next
-    block with room, falling back to the least-loaded block (the same
-    hardened deferral as numpy ``schedule`` — DESIGN.md §3). Inactive
-    slots are skipped and get block -1.
-
-    workload: (R,) predicted pairs; active: (R,) bool. Returns (R,) int32.
-    """
-    b = max(int(num_blocks), 1)
-    # Mirror numpy schedule()'s int64 entry cast (truncation included) so
-    # the fit decisions below see the same values as the golden reference.
-    wl = workload.astype(jnp.int32).astype(jnp.float32)
-    act = active.astype(bool)
-    n_active = jnp.sum(act.astype(jnp.int32)).astype(jnp.float32)
-    total = jnp.sum(jnp.where(act, wl, 0.0))
-    w_ideal = jnp.maximum(total / b, 1.0)
-    n_avg = jnp.maximum(n_active / b, 1.0)
-    cap = (1.0 + 1.0 / n_avg) * w_ideal
-    offsets = jnp.arange(b, dtype=jnp.int32)
-
-    def body(carry, x):
-        accs, cur = carry
-        w, a = x
-        fits_cur = accs[cur] + w <= cap
-        cand = jnp.mod(cur + 1 + offsets, b)           # cur+1 .. cur+b
-        fits = accs[cand] + w <= cap
-        deferred = jnp.where(jnp.any(fits), cand[jnp.argmax(fits)],
-                             jnp.argmin(accs).astype(jnp.int32))
-        tgt = jnp.where(fits_cur, cur, deferred)
-        accs = jnp.where(a, accs.at[tgt].add(w), accs)
-        new_cur = jnp.where(a, tgt, cur)
-        return (accs, new_cur), jnp.where(a, tgt, -1)
-
-    init = (jnp.zeros((b,), jnp.float32), jnp.int32(0))
-    _, blocks = jax.lax.scan(body, init, (wl, act))
-    return blocks.astype(jnp.int32)
-
-
-def order_within_blocks(block_of: jax.Array, key: jax.Array,
-                        tiebreak: jax.Array) -> jax.Array:
-    """(R,) execution position of each slot within its block (device).
-
-    ``key`` is the primary ordering (workload for the paper's
-    light-to-heavy rule, visit position for arrival order); ties break on
-    ``tiebreak`` (tile id — matching numpy ``schedule``'s stable sorts).
-    Slots with block -1 get position 0, like the numpy reference.
-    """
-    r = block_of.shape[0]
-    sort_idx = jnp.lexsort((tiebreak, key, block_of))
-    blk_sorted = block_of[sort_idx]
-    pos = jnp.arange(r, dtype=jnp.int32)
-    is_start = jnp.concatenate(
-        [jnp.ones((1,), bool), blk_sorted[1:] != blk_sorted[:-1]])
-    seg_start = jax.lax.cummax(jnp.where(is_start, pos, 0))
-    order = jnp.zeros((r,), jnp.int32).at[sort_idx].set(pos - seg_start)
-    return jnp.where(block_of >= 0, order, 0)
-
-
-def ldu_schedule(workload: jax.Array, num_blocks: int, *,
-                 policy: str = "ls_gaussian",
-                 tiles_x: Optional[int] = None,
-                 tiles_y: Optional[int] = None,
-                 active: Optional[jax.Array] = None
-                 ) -> Tuple[jax.Array, jax.Array]:
-    """Device (jnp) port of ``schedule``: same policies, same assignments.
-
-    Returns ``(block_of_tile, order_in_block)``, both (T,) int32, matching
-    the numpy golden reference bit-for-bit on identical inputs. Fully
-    jit/vmap/scan-compatible — this is what the plan-driven renderer runs
-    inside the scanned streaming engine.
-    """
-    workload = jnp.asarray(workload).astype(jnp.int32)  # numpy entry cast
-    t = workload.shape[0]
-    b = max(int(num_blocks), 1)
-    if active is None:
-        active = jnp.ones((t,), bool)
-    active = active.astype(bool)
-    tile_ids = jnp.arange(t, dtype=jnp.int32)
-    pos_active = jnp.cumsum(active.astype(jnp.int32)) - 1
-    n_active = jnp.sum(active.astype(jnp.int32))
-
-    if policy == "static_blocked":
-        chunk = jnp.maximum((n_active + b - 1) // b, 1)
-        blk = jnp.minimum(pos_active // chunk, b - 1)
-        block_of = jnp.where(active, blk, -1).astype(jnp.int32)
-    elif policy == "round_robin":
-        block_of = jnp.where(active, pos_active % b, -1).astype(jnp.int32)
-    elif policy == "dynamic":
-        def body(loads, x):
-            w, a = x
-            j = jnp.argmin(loads).astype(jnp.int32)
-            loads = jnp.where(a, loads.at[j].add(w), loads)
-            return loads, jnp.where(a, j, -1)
-        _, block_of = jax.lax.scan(
-            body, jnp.zeros((b,), jnp.float32),
-            (workload.astype(jnp.float32), active))
-        block_of = block_of.astype(jnp.int32)
-    elif policy == "ls_gaussian":
-        if tiles_x is None or tiles_y is None:
-            raise ValueError("ls_gaussian policy needs tiles_x/tiles_y for "
-                             "Morton traversal")
-        visit = jnp.argsort(morton_rank(tiles_x, tiles_y))
-        blk_v = greedy_fill(workload[visit], active[visit], b)
-        block_of = jnp.full((t,), -1, jnp.int32).at[visit].set(blk_v)
-    else:
-        raise ValueError(f"unknown policy {policy!r}")
-
-    key = workload.astype(jnp.int32) if policy == "ls_gaussian" else tile_ids
-    order_in = order_within_blocks(block_of, key, tile_ids)
-    return block_of, order_in
-
-
 def load_stats(sched: Schedule, workload: np.ndarray) -> dict:
     """Imbalance diagnostics: per-block totals, max/mean ratio."""
     loads = np.zeros(sched.num_blocks)
     for j in range(sched.num_blocks):
-        ids = np.where(sched.block_of_tile == j)[0]
+        ids = np.where(sched.block_of == j)[0]
         loads[j] = workload[ids].sum()
     mean = loads.mean() if loads.size else 0.0
     return {

@@ -7,8 +7,8 @@ entirely, re-rendered tiles go through the pipeline with DPES depth culling.
 
 Every frame renders through ONE shared stage pipeline,
 ``render_planned_frame``: preprocess -> intersect -> (R, K) compacted
-binning with DPES limits -> device-LDU schedule -> raster over the plan's
-R slots -> scatter back to the full frame. Full frames carry an all-tiles
+binning with DPES limits -> raster over the plan's R slots in Morton
+order -> scatter back to the full frame. Full frames carry an all-tiles
 ``TilePlan`` (R = T); TWSR frames carry the warp-predicted re-render set
 compacted to ``R = rerender_capacity`` — so sparse-frame bins and raster
 scale with R instead of T (DESIGN.md §2). TAIT's pair list covers the
@@ -18,9 +18,9 @@ whole grid, and its one sort serves any R (DESIGN.md §3).
 ``render_trajectory`` (core/engine.py) is the production driver — the
 whole loop as one jitted ``lax.scan``; ``render_trajectory_py`` below is
 the host-side reference loop kept for golden comparison. Per-frame work
-summaries (``FrameRecord``) — including the device-LDU block assignments
-and per-block load summaries — feed both the GPU-style cost model and the
-streaming accelerator simulator (core/streaming.py).
+summaries (``FrameRecord``) feed both the GPU-style cost model and the
+streaming accelerator simulator (core/streaming.py), which builds the
+LDU schedule on the host from the recorded ``sort_pairs`` and ``active``.
 """
 from __future__ import annotations
 
@@ -59,7 +59,6 @@ class RenderConfig:
     near: float = 0.05
     min_coverage: float = warp_mod.MIN_COVERAGE
     rerender_capacity: Optional[int] = None  # R: static cap on plan slots
-    ldu_blocks: int = 32                # B: parallel raster blocks (LDU)
     # Temporal contribution culling (core/culling.py, DESIGN.md §12): on
     # sparse frames, drop intersection pairs whose Gaussian contributed
     # < cull_threshold blend mass at the last key frame, before binning.
@@ -109,9 +108,6 @@ class FrameRecord(NamedTuple):
     tiles_interpolated: jax.Array  # () int32
     overflow_pairs: jax.Array   # () int32 — bin-capacity overflow
     overflow_tiles: jax.Array   # () int32 — rerender_capacity overflow
-    block_of_tile: jax.Array    # (T,) int32 — device-LDU block (-1 = none)
-    order_in_block: jax.Array   # (T,) int32 — light-to-heavy position
-    block_load: jax.Array       # (B,) int32 — predicted pairs per block
     culled_pairs: jax.Array     # () int32 — pairs removed by culling
     pair_budget_overflow: jax.Array  # () int32 — pairs past the pair budget
     stage1_pairs: jax.Array     # () int32 — stage-1 pairs on the whole grid
@@ -272,10 +268,10 @@ def render_planned_frame(scene, cam: Camera, plan: TilePlan,
 
     preprocess -> intersect against the plan's R slots -> contribution
     cull -> (R, K) compacted binning (with per-slot DPES depth limits) ->
-    device-LDU schedule over the slots -> raster the slots -> scatter
-    back to the (H, W) frame. TAIT intersects and bins through a pair
-    list sorted once (``_pair_list_bins``); the other intersect methods
-    through the dense mask and ``top_k`` (``_dense_bins``).
+    raster the slots -> scatter back to the (H, W) frame. TAIT intersects
+    and bins through a pair list sorted once (``_pair_list_bins``); the
+    other intersect methods through the dense mask and ``top_k``
+    (``_dense_bins``).
 
     dpes_depth: optional (T,) per-tile early-stop depth (inf = no prior);
     gathered to the plan's slots before binning.
@@ -290,8 +286,8 @@ def render_planned_frame(scene, cam: Camera, plan: TilePlan,
 
     Returns ``(out, plan, n_gaussians, stats)`` where ``out`` is the
     full-frame RenderOutput (unplanned tiles empty), ``plan`` now carries
-    the LDU schedule + per-slot workloads, and ``stats`` the remaining
-    per-slot counters the wrappers fold into a ``FrameRecord``.
+    the per-slot workloads (post-DPES bin counts), and ``stats`` the
+    remaining per-slot counters the wrappers fold into a ``FrameRecord``.
     """
     with annotate("repro.frame/preprocess"):
         proj = preprocess(scene, cam, near=cfg.near)
@@ -310,11 +306,9 @@ def render_planned_frame(scene, cam: Camera, plan: TilePlan,
         else _dense_bins
     bins, plan, stats = stage(proj, grid, plan, cfg, limit, cull_prior,
                               cull_gate)
-    # Device LDU (paper Sec. V-B): post-DPES counts are the workload
-    # prediction; the greedy Morton fill + light-to-heavy order runs in
-    # jnp, inside whatever jit/scan wraps this frame.
-    with annotate("repro.frame/ldu_schedule"):
-        plan = plan_mod.schedule_plan(plan, bins.count, cfg.ldu_blocks)
+    # Post-DPES counts are the LDU's workload prediction (paper Sec.
+    # V-B), recorded as sort_pairs; the simulator schedules them on host.
+    plan = plan._replace(workload=bins.count.astype(jnp.int32))
 
     with annotate("repro.frame/raster"):
         out = render_plan_slots(proj, bins, slots.origins, plan.tile_ids,
@@ -351,9 +345,6 @@ def _plan_record(plan: TilePlan, stats: PlanStats, out: RenderOutput,
         tiles_interpolated=tiles_interpolated,
         overflow_pairs=stats.overflow_pairs,
         overflow_tiles=plan.overflow_tiles,
-        block_of_tile=scat(plan.block_of, fill=-1),
-        order_in_block=scat(plan.order_in_block),
-        block_load=plan_mod.block_loads(plan, cfg.ldu_blocks),
         culled_pairs=stats.culled_pairs,
         pair_budget_overflow=stats.pair_budget_overflow,
         stage1_pairs=stats.stage1_pairs,

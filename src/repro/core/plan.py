@@ -2,7 +2,7 @@
 
 The paper's central claim is that streaming 3DGS should do work
 proportional to what actually changed: TWSR picks the re-render tile set
-and the LDU maps predicted per-tile workloads onto parallel blocks. The
+and its DPES-predicted per-tile workloads are what the LDU schedules. The
 ``TilePlan`` is that decision reified as a first-class device value:
 
   tile_ids       (R,) int32  tile ids in Morton visit order, active
@@ -12,19 +12,20 @@ and the LDU maps predicted per-tile workloads onto parallel blocks. The
                              with R instead of the full tile count T.
   slot_active    (R,) bool   padded slots (beyond the re-render set) are
                              inactive and contribute nothing.
-  workload       (R,) int32  DPES-predicted pairs per slot (the LDU's
-                             scheduling input; filled after binning).
-  block_of       (R,) int32  device-LDU block assignment (-1 inactive).
-  order_in_block (R,) int32  light-to-heavy execution position.
+  workload       (R,) int32  DPES-predicted pairs per slot (filled after
+                             binning; recorded as ``sort_pairs``, the
+                             host LDU's scheduling input).
   overflow_tiles ()   int32  re-render tiles dropped because they did not
                              fit in R (they degrade to interpolation).
 
 Full frames carry an all-tiles plan (R = T); TWSR sparse frames carry the
 warp-predicted re-render set compacted to ``R = rerender_capacity``. Both
 render through the same ``pipeline.render_planned_frame``. Everything is
-shape-static and jnp, so plans are built AND scheduled inside the jitted
-``lax.scan`` streaming engine (core/engine.py) with no host callback;
-numpy ``load_balance.schedule`` remains the golden reference.
+shape-static and jnp, so plans are built inside the jitted ``lax.scan``
+streaming engine (core/engine.py) with no host callback. The LDU block
+schedule is not part of the plan: nothing on the chip reads it, and the
+accelerator simulator builds it on the host with numpy
+``load_balance.schedule`` (core/streaming.py).
 """
 from __future__ import annotations
 
@@ -42,8 +43,6 @@ class TilePlan(NamedTuple):
     tile_ids: jax.Array        # (R,) int32
     slot_active: jax.Array     # (R,) bool
     workload: jax.Array        # (R,) int32
-    block_of: jax.Array        # (R,) int32
-    order_in_block: jax.Array  # (R,) int32
     overflow_tiles: jax.Array  # () int32
 
     @property
@@ -57,8 +56,6 @@ def _blank(tile_ids: jax.Array, slot_active: jax.Array,
     return TilePlan(
         tile_ids=tile_ids.astype(jnp.int32), slot_active=slot_active,
         workload=jnp.zeros((r,), jnp.int32),
-        block_of=jnp.full((r,), -1, jnp.int32),
-        order_in_block=jnp.zeros((r,), jnp.int32),
         overflow_tiles=overflow_tiles)
 
 
@@ -87,24 +84,6 @@ def sparse_plan(rerender: jax.Array, tiles_x: int, tiles_y: int,
     overflow = (jnp.sum(rerender.astype(jnp.int32))
                 - jnp.sum(slot_active.astype(jnp.int32)))
     return _blank(ids, slot_active, overflow)
-
-
-def schedule_plan(plan: TilePlan, workload: jax.Array,
-                  num_blocks: int) -> TilePlan:
-    """Run the device LDU over the plan's slots (paper Sec. V-B).
-
-    Slots are already in Morton visit order, so the greedy capacity fill
-    scans them directly; intra-block order is light-to-heavy with tile-id
-    tie-breaks — bit-identical to numpy ``load_balance.schedule`` with
-    ``policy="ls_gaussian"`` on the same workloads/active set.
-    """
-    workload = workload.astype(jnp.int32)
-    block_of = load_balance.greedy_fill(workload, plan.slot_active,
-                                        num_blocks)
-    order = load_balance.order_within_blocks(block_of, workload,
-                                             plan.tile_ids)
-    return plan._replace(workload=workload, block_of=block_of,
-                         order_in_block=order)
 
 
 def scatter_slots(plan: TilePlan, values: jax.Array, num_tiles: int,
@@ -142,9 +121,3 @@ def rerender_demand(active, overflow_tiles):
     return (jnp.sum(jnp.asarray(active).astype(jnp.int32), axis=-1)
             + jnp.asarray(overflow_tiles).astype(jnp.int32))
 
-
-def block_loads(plan: TilePlan, num_blocks: int) -> jax.Array:
-    """(B,) predicted pairs per LDU block — the FrameRecord load summary."""
-    idx = jnp.where(plan.block_of >= 0, plan.block_of, num_blocks)
-    wl = jnp.where(plan.slot_active, plan.workload, 0)
-    return jnp.zeros((num_blocks,), jnp.int32).at[idx].add(wl, mode="drop")

@@ -66,13 +66,6 @@ class FrameWork:
     n_warp_pixels: int = 0        # VTU work (0 for full frames)
     tiles_x: int = 0
     tiles_y: int = 0
-    # Device-LDU schedule recorded by the plan-driven renderer
-    # (FrameRecord.block_of_tile / order_in_block); lets the simulator
-    # serve exactly what the jitted engine scheduled (policy="recorded")
-    # instead of re-deriving it host-side.
-    block_of: Optional[np.ndarray] = None       # (T,) int, -1 = unscheduled
-    order_in_block: Optional[np.ndarray] = None  # (T,) int
-    num_blocks: int = 0           # B the device schedule was built for
 
 
 def frameworks_from_stacked(records, tiles_x: int, tiles_y: int,
@@ -98,18 +91,13 @@ def frameworks_from_stacked(records, tiles_x: int, tiles_y: int,
     sort = np.asarray(records.sort_pairs)
     raster = np.asarray(records.raster_pairs)
     active = np.asarray(records.active)
-    block_of = np.asarray(records.block_of_tile)
-    order_in = np.asarray(records.order_in_block)
-    num_blocks = int(np.asarray(records.block_load).shape[-1])
     return [FrameWork(
         n_gaussians=int(n_gaussians[f]),
         candidate_pairs=int(candidate[f]),
         raw_pairs=raw[f], sort_pairs=sort[f], raster_pairs=raster[f],
         active=active[f],
         n_warp_pixels=0 if is_full[f] else n_pixels,
-        tiles_x=tiles_x, tiles_y=tiles_y,
-        block_of=block_of[f], order_in_block=order_in[f],
-        num_blocks=num_blocks)
+        tiles_x=tiles_x, tiles_y=tiles_y)
         for f in range(is_full.shape[0])]
 
 
@@ -180,10 +168,9 @@ def simulate_sequence(frames: Sequence[FrameWork], cfg: AcceleratorConfig,
                                light_to_heavy=False
       - + LD1 (inter-block)  : policy="ls_gaussian", light_to_heavy=False
       - + LD2 (intra-block)  : light_to_heavy=True (full LS-Gaussian)
-      - recorded             : policy="recorded" — serve the device-LDU
-                               schedule the plan-driven renderer recorded
-                               in the FrameRecord (no host re-derivation;
-                               requires matching cfg.num_blocks)
+
+    Each frame's schedule is numpy ``load_balance.schedule`` over the
+    frame's recorded workloads and re-render set.
     """
     timings: List[FrameTiming] = []
     ccu_free = 0.0
@@ -201,36 +188,17 @@ def simulate_sequence(frames: Sequence[FrameWork], cfg: AcceleratorConfig,
         prep_end = max(ccu_end, vtu_end)
         ccu_free, vtu_free = ccu_end, vtu_end
 
-        if policy == "recorded":
-            if work.block_of is None or work.order_in_block is None:
-                raise ValueError(
-                    "policy='recorded' needs FrameWork.block_of / "
-                    "order_in_block from the plan-driven renderer")
-            if work.num_blocks and work.num_blocks != cfg.num_blocks:
-                raise ValueError(
-                    f"recorded schedule was built for {work.num_blocks} "
-                    f"blocks but the simulator has {cfg.num_blocks}")
-            if np.max(work.block_of, initial=-1) >= cfg.num_blocks:
-                raise ValueError(
-                    f"recorded schedule assigns block "
-                    f"{int(np.max(work.block_of))} but the simulator only "
-                    f"has {cfg.num_blocks} blocks")
-            sched = Schedule(
-                block_of_tile=np.asarray(work.block_of, np.int64),
-                order_in_block=np.asarray(work.order_in_block, np.int64),
-                num_blocks=cfg.num_blocks)
-        else:
-            # Without DPES the LDU only knows raw (pre-cull) pair counts;
-            # with it, post-cull counts are an accurate raster predictor.
-            wl = work.sort_pairs if workload_source == "dpes" \
-                else work.raw_pairs
-            sched = schedule(np.asarray(wl), cfg.num_blocks, policy=policy,
-                             tiles_x=work.tiles_x, tiles_y=work.tiles_y,
-                             active=np.asarray(work.active))
-            if policy == "ls_gaussian" and not light_to_heavy:
-                # strip the intra-block reordering: arrival (Morton) order
-                sched = dataclasses.replace(
-                    sched, order_in_block=_arrival_order(sched, work))
+        # Without DPES the LDU only knows raw (pre-cull) pair counts;
+        # with it, post-cull counts are an accurate raster predictor.
+        wl = work.sort_pairs if workload_source == "dpes" \
+            else work.raw_pairs
+        sched = schedule(np.asarray(wl), cfg.num_blocks, policy=policy,
+                         tiles_x=work.tiles_x, tiles_y=work.tiles_y,
+                         active=np.asarray(work.active))
+        if policy == "ls_gaussian" and not light_to_heavy:
+            # strip the intra-block reordering: arrival (Morton) order
+            sched = dataclasses.replace(
+                sched, order_in_block=_arrival_order(sched, work))
 
         frame_end, gsu_free, vru_free, t = _simulate_raster(
             work, sched, cfg, prep_end, gsu_free, vru_free)
@@ -248,7 +216,7 @@ def _arrival_order(sched: Schedule, work: FrameWork) -> np.ndarray:
     order = np.zeros_like(sched.order_in_block)
     visit = morton_order(work.tiles_x, work.tiles_y)
     for j in range(sched.num_blocks):
-        ids = [tid for tid in visit if sched.block_of_tile[tid] == j]
+        ids = [tid for tid in visit if sched.block_of[tid] == j]
         for pos, tid in enumerate(ids):
             order[tid] = pos
     return order

@@ -43,11 +43,12 @@ max wait, and a Jain fairness index over service shares next to the
 global metrics.
 
 ``sim_latency=True`` closes the loop with the paper's accelerator model:
-every rendered frame's ``FrameRecord`` (with its recorded device-LDU
-schedule) is folded into a bounded trace and ``report()`` replays it
-through ``core/streaming.simulate_sequence(policy="recorded")`` — so
-serve_bench.json shows the simulated ASIC cycles next to the wall-clock
-latencies for the very frames this process served.
+every rendered frame's ``FrameRecord`` is folded into a bounded trace and
+``report()`` replays it through ``core/streaming.simulate_sequence`` with
+the paper's LDU (``policy="ls_gaussian"`` on the recorded DPES workloads,
+light-to-heavy), scheduled on the host — so serve_bench.json shows the
+simulated ASIC cycles next to the wall-clock latencies for the very
+frames this process served.
 
 Traffic: ``PoissonTraffic`` drives the steady-state benchmarks (Poisson
 arrivals of heterogeneous dolly/orbit trajectories round-robined over
@@ -736,14 +737,16 @@ class StreamServer:
 
     def _sim_report(self) -> Optional[dict]:
         """Replay the served frames through the accelerator model —
-        simulated ASIC cycles for the exact schedules the jitted engine
-        recorded (policy="recorded", streaming pipeline on)."""
+        simulated ASIC cycles under the paper's LDU, scheduled on the
+        host from each frame's recorded DPES workloads and re-render set
+        (streaming pipeline on)."""
         frames, trimmed = self._sim_frameworks()
         if not frames:
             return None
-        acfg = AcceleratorConfig(num_blocks=self.base_cfg.ldu_blocks)
-        timings = simulate_sequence(frames, acfg, policy="recorded",
-                                    streaming=True)
+        acfg = AcceleratorConfig()
+        timings = simulate_sequence(frames, acfg, policy="ls_gaussian",
+                                    workload_source="dpes",
+                                    light_to_heavy=True, streaming=True)
         agg = throughput(timings, acfg.num_blocks)
         # Per-frame service latency in the streaming pipeline: the gap
         # this frame adds to the completion front (frame_end is

@@ -30,8 +30,7 @@ from repro.scenes.trajectory import dolly_trajectory
 
 _BASE_FIELDS = ("is_full", "n_gaussians", "candidate_pairs", "raw_pairs",
                 "sort_pairs", "raster_pairs", "active",
-                "tiles_interpolated", "overflow_pairs", "overflow_tiles",
-                "block_of_tile", "order_in_block", "block_load")
+                "tiles_interpolated", "overflow_pairs", "overflow_tiles")
 
 
 def _cfg(**kw):

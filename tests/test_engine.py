@@ -14,8 +14,7 @@ N_FRAMES = 7
 
 _COUNT_FIELDS = ("n_gaussians", "candidate_pairs", "raw_pairs",
                  "sort_pairs", "raster_pairs", "tiles_interpolated",
-                 "overflow_pairs", "overflow_tiles",
-                 "block_of_tile", "order_in_block", "block_load")
+                 "overflow_pairs", "overflow_tiles")
 
 
 def _poses(n=N_FRAMES, dx=0.0):

@@ -1,7 +1,7 @@
 """TilePlan-driven rendering (DESIGN.md §2): the compacted sparse path is
-equivalent to the dense path, compiles to (R, K)-shaped stages, and the
-device-LDU schedule recorded inside the jitted scan matches the numpy
-golden ``load_balance.schedule``."""
+equivalent to the dense path, compiles to (R, K)-shaped stages with no
+loop over the plan's slots, and the records carry what the host LDU
+(numpy ``load_balance.schedule``) schedules the simulator's frames from."""
 import functools
 
 import jax
@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import binning, intersect, plan as plan_mod, projection, raster
+from repro.core import (binning, engine, intersect, plan as plan_mod,
+                        projection, raster, streaming)
 from repro.core.load_balance import schedule
 from repro.core.pipeline import (RenderConfig, render_full_frame,
                                  render_sparse_frame, render_trajectory)
@@ -17,8 +18,7 @@ from repro.core.streaming import (AcceleratorConfig, frameworks_from_stacked,
                                   simulate_sequence)
 from repro.scenes.trajectory import dolly_trajectory
 
-_PER_TILE_FIELDS = ("raw_pairs", "sort_pairs", "raster_pairs", "active",
-                    "block_of_tile", "order_in_block")
+_PER_TILE_FIELDS = ("raw_pairs", "sort_pairs", "raster_pairs", "active")
 
 
 def _poses(n=4):
@@ -106,6 +106,15 @@ def test_full_frame_matches_dense_reference(small_scene, small_cam):
                                   np.asarray(bins.count))
 
 
+def _sub_jaxprs(eqn):
+    """The jaxprs an equation holds (scan/cond/pjit bodies, ...)."""
+    for p in eqn.params.values():
+        for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+            inner = getattr(sub, "jaxpr", sub)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
 def _collect_shapes(jaxpr, acc):
     """All output-var shapes in a jaxpr, recursing into sub-jaxprs."""
     for eqn in jaxpr.eqns:
@@ -113,11 +122,8 @@ def _collect_shapes(jaxpr, acc):
             aval = getattr(v, "aval", None)
             if aval is not None and hasattr(aval, "shape"):
                 acc.add(tuple(aval.shape))
-        for p in eqn.params.values():
-            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
-                inner = getattr(sub, "jaxpr", sub)
-                if hasattr(inner, "eqns"):
-                    _collect_shapes(inner, acc)
+        for inner in _sub_jaxprs(eqn):
+            _collect_shapes(inner, acc)
 
 
 def test_sparse_stages_are_plan_shaped(small_scene, small_cam):
@@ -153,50 +159,126 @@ def test_sparse_stages_are_plan_shaped(small_scene, small_cam):
 
 
 def test_recorded_schedule_matches_numpy_golden(small_scene, small_cam):
-    """The device LDU runs inside the jitted scan (no host callback) and
-    its recorded block assignments match numpy ``schedule()`` on the
-    identical workloads/active sets, frame by frame."""
-    cfg = RenderConfig(window=2, ldu_blocks=8)
-    res = render_trajectory(small_scene, small_cam, _poses(4), cfg)
-    for f in range(4):
+    """The records carry what the host LDU schedules from:
+    ``frameworks_from_stacked`` hands the simulator each frame's recorded
+    ``sort_pairs`` and ``active`` unchanged, and numpy ``schedule()`` on
+    them puts every re-render tile in exactly one block, schedules no
+    other tile, and orders each block light-to-heavy, frame by frame."""
+    b = 8
+    tx, ty = small_cam.tiles_x, small_cam.tiles_y
+    res = render_trajectory(small_scene, small_cam, _poses(4),
+                            RenderConfig(window=2))
+    frames = frameworks_from_stacked(res.records, tx, ty,
+                                     small_cam.width * small_cam.height)
+    assert len(frames) == 4
+    for f, work in enumerate(frames):
         rec = res.records[f]
         wl = np.asarray(rec.sort_pairs)
         active = np.asarray(rec.active)
-        ref = schedule(wl, cfg.ldu_blocks, policy="ls_gaussian",
-                       tiles_x=small_cam.tiles_x, tiles_y=small_cam.tiles_y,
-                       active=active)
-        np.testing.assert_array_equal(np.asarray(rec.block_of_tile),
-                                      ref.block_of_tile, err_msg=f"frame {f}")
-        np.testing.assert_array_equal(np.asarray(rec.order_in_block),
-                                      ref.order_in_block, err_msg=f"frame {f}")
-        # per-block load summary is consistent with the assignment
-        loads = np.asarray(rec.block_load)
-        assert loads.shape == (cfg.ldu_blocks,)
-        for b in range(cfg.ldu_blocks):
-            assert loads[b] == wl[ref.block_of_tile == b].sum()
+        np.testing.assert_array_equal(work.sort_pairs, wl)
+        np.testing.assert_array_equal(work.active, active)
+        assert active.any(), f"frame {f} re-renders nothing"
+        sched = schedule(work.sort_pairs, b, policy="ls_gaussian",
+                         tiles_x=tx, tiles_y=ty, active=work.active)
+        np.testing.assert_array_equal(sched.block_of >= 0, active,
+                                      err_msg=f"frame {f}")
+        assert sched.block_of.max() < b
+        for j in range(b):
+            assert np.all(np.diff(wl[sched.tiles_of_block(j)]) >= 0), \
+                f"frame {f} block {j} is not light-to-heavy"
 
 
-def test_simulator_consumes_recorded_schedule(small_scene, small_cam):
-    """policy='recorded' serves the FrameRecord's device schedule and
-    reproduces the host-side ls_gaussian simulation exactly."""
-    cfg = RenderConfig(window=2, ldu_blocks=8)
-    res = render_trajectory(small_scene, small_cam, _poses(4), cfg)
-    frames = frameworks_from_stacked(
-        res.records, small_cam.tiles_x, small_cam.tiles_y,
-        small_cam.width * small_cam.height)
-    assert frames[0].num_blocks == cfg.ldu_blocks
-    acfg = AcceleratorConfig(num_blocks=cfg.ldu_blocks)
-    rec_t = simulate_sequence(frames, acfg, policy="recorded")
-    ls_t = simulate_sequence(frames, acfg, policy="ls_gaussian",
-                             workload_source="dpes", light_to_heavy=True)
-    for a, b in zip(rec_t, ls_t):
-        assert a.frame_end == pytest.approx(b.frame_end)
-        assert a.sort_stall == pytest.approx(b.sort_stall)
-        assert a.utilization == pytest.approx(b.utilization)
+def test_simulator_consumes_recorded_schedule(small_scene, small_cam,
+                                              monkeypatch):
+    """``simulate_sequence(policy="ls_gaussian", workload_source="dpes",
+    light_to_heavy=True)`` serves, frame by frame, numpy ``schedule()``
+    of the frame's recorded ``sort_pairs``/``active``, and each frame's
+    timing is that schedule's replay."""
+    tx, ty = small_cam.tiles_x, small_cam.tiles_y
+    res = render_trajectory(small_scene, small_cam, _poses(4),
+                            RenderConfig(window=2))
+    frames = frameworks_from_stacked(res.records, tx, ty,
+                                     small_cam.width * small_cam.height)
+    acfg = AcceleratorConfig(num_blocks=8)
+    real = streaming._simulate_raster
+    calls = []
 
-    bad = AcceleratorConfig(num_blocks=cfg.ldu_blocks * 2)
-    with pytest.raises(ValueError, match="recorded schedule"):
-        simulate_sequence(frames, bad, policy="recorded")
+    def spy(work, sched, cfg, prep_end, gsu_free, vru_free):
+        calls.append((sched, prep_end, gsu_free, vru_free.copy()))
+        return real(work, sched, cfg, prep_end, gsu_free, vru_free)
+
+    monkeypatch.setattr(streaming, "_simulate_raster", spy)
+    timings = simulate_sequence(frames, acfg, policy="ls_gaussian",
+                                workload_source="dpes", light_to_heavy=True)
+    assert len(calls) == len(timings) == 4
+    for f, (sched, prep_end, gsu_free, vru_free) in enumerate(calls):
+        rec = res.records[f]
+        ref = schedule(np.asarray(rec.sort_pairs), acfg.num_blocks,
+                       policy="ls_gaussian", tiles_x=tx, tiles_y=ty,
+                       active=np.asarray(rec.active))
+        np.testing.assert_array_equal(sched.block_of, ref.block_of,
+                                      err_msg=f"frame {f}")
+        np.testing.assert_array_equal(sched.order_in_block,
+                                      ref.order_in_block,
+                                      err_msg=f"frame {f}")
+        *_, ref_t = real(frames[f], ref, acfg, prep_end, gsu_free, vru_free)
+        assert timings[f] == ref_t, f"frame {f}"
+
+
+def _loops(jaxpr, acc):
+    """``(primitive, static trip count)`` of every ``scan`` and ``while``
+    in a jaxpr, recursing into sub-jaxprs (a while's count is None)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            acc.append(("scan", eqn.params["length"]))
+        elif eqn.primitive.name == "while":
+            acc.append(("while", None))
+        for inner in _sub_jaxprs(eqn):
+            _loops(inner, acc)
+
+
+@pytest.mark.parametrize("driver", ["solo", "streams"])
+@pytest.mark.parametrize("frame", ["key", "sparse"])
+def test_frame_has_no_loop_over_plan_slots(small_scene, wide_cam, frame,
+                                           driver):
+    """No loop in a frame's program runs once per plan slot or per tile:
+    the LDU schedule, a serial fill over the slots that nothing on the
+    chip reads, is built on the host. Shorter loops stay (the binary
+    searches, the raster's blend chunks, inpainting); a ``while`` has no
+    static trip count, so none may appear. ``streams`` is
+    ``render_streams`` at B = 2, both branches under ``vmap``."""
+    rcap, n_frames = 7, 3
+    t = wide_cam.num_tiles
+    assert len({rcap, t, n_frames}) == 3
+    cfg = RenderConfig(window=1 if frame == "key" else 3,
+                       rerender_capacity=rcap, capacity=128)
+    poses = jnp.stack([
+        dolly_trajectory(n_frames, start=(0.03 * i, -0.3, -2.0),
+                         target=(0.0, 0.0, 6.0)) for i in range(2)])
+    if driver == "streams":
+        def fn(scene, poses_b):
+            res = engine.render_streams(scene, wide_cam, poses_b, cfg,
+                                        phases=(0, 1),
+                                        counts=(n_frames,) * 2)
+            return res.frames, res.records.stacked
+        jx = jax.make_jaxpr(fn)(small_scene, poses)
+    elif frame == "key":
+        jx = jax.make_jaxpr(functools.partial(render_full_frame, cfg=cfg))(
+            small_scene, wide_cam.with_pose(poses[0, 0]))
+    else:
+        ref_cam = wide_cam.with_pose(poses[0, 0])
+        tgt_cam = wide_cam.with_pose(poses[0, 1])
+        _, state, _ = jax.eval_shape(
+            functools.partial(render_full_frame, cfg=cfg), small_scene,
+            ref_cam)
+        jx = jax.make_jaxpr(functools.partial(render_sparse_frame, cfg=cfg))(
+            small_scene, ref_cam, tgt_cam, state)
+    loops = []
+    _loops(jx.jaxpr, loops)
+    assert any(kind == "scan" for kind, _ in loops), "no loop found at all"
+    slot_loops = [(kind, n) for kind, n in loops
+                  if kind == "while" or n in (rcap, t)]
+    assert not slot_loops, f"R = {rcap}, T = {t}: {loops}"
 
 
 def test_scatter_slots_masks_inactive(small_cam):

@@ -1,6 +1,7 @@
 """Serving subsystem (repro.serve): masked/ragged batching equivalence,
 session lifecycle, continuous batcher bookkeeping, bucketed executable
 cache, device placement, and the serve loop smoke."""
+import dataclasses
 import json
 import os
 import subprocess
@@ -15,6 +16,8 @@ import pytest
 
 from repro.core import engine
 from repro.core.pipeline import RenderConfig
+from repro.core.streaming import (AcceleratorConfig, frameworks_from_stacked,
+                                  simulate_sequence, throughput)
 from repro.scenes.trajectory import dolly_trajectory
 from repro.serve import (ContinuousBatcher, ExecutableCache, PoissonTraffic,
                          ServeConfig, SessionManager, StreamServer,
@@ -25,8 +28,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _RECORD_FIELDS = ("is_full", "n_gaussians", "candidate_pairs", "raw_pairs",
                   "sort_pairs", "raster_pairs", "active",
-                  "tiles_interpolated", "overflow_pairs", "overflow_tiles",
-                  "block_of_tile", "order_in_block", "block_load")
+                  "tiles_interpolated", "overflow_pairs", "overflow_tiles")
 
 
 def _poses(n, dx=0.0):
@@ -336,3 +338,36 @@ def test_serve_smoke(small_scene, small_cam):
     assert rep["capacity"] in scfg.r_buckets
     assert not srv.manager.sessions      # no leaked sessions or slots
     assert srv.batcher.bound == 0
+
+
+def test_sim_report_replays_served_frames(small_scene, wide_cam):
+    """With ``sim_latency`` the report's ``sim`` is the host simulation of
+    the frames the server rendered: the paper's LDU on each frame's
+    recorded DPES workloads, light-to-heavy, streaming pipeline on."""
+    cfg = RenderConfig(window=3, capacity=128)
+    scfg = ServeConfig(slots=1, chunk=4, r_buckets=(8,), sim_latency=True)
+    srv = StreamServer(small_scene, wide_cam, cfg, scfg)
+    poses = _poses(6)
+    srv.attach(np.asarray(poses))               # 2 rounds: 4 + 2 frames
+    sim = srv.run(max_rounds=10)["sim"]
+    assert sim["frames"] == 6 and sim["frames_dropped"] == 0
+
+    solo = engine.render_trajectory(
+        small_scene, wide_cam, poses,
+        dataclasses.replace(cfg, rerender_capacity=srv.capacity))
+    frames = frameworks_from_stacked(
+        solo.records, wide_cam.tiles_x, wide_cam.tiles_y,
+        wide_cam.width * wide_cam.height)
+    acfg = AcceleratorConfig()
+    timings = simulate_sequence(frames, acfg, policy="ls_gaussian",
+                                workload_source="dpes", light_to_heavy=True,
+                                streaming=True)
+    agg = throughput(timings, acfg.num_blocks)
+    service = np.diff([t.frame_end for t in timings], prepend=0.0)
+    assert sim["cycles_per_frame"] == round(agg["cycles_per_frame"], 1)
+    assert sim["utilization"] == round(agg["utilization"], 4)
+    assert sim["sort_stall_cycles"] == round(agg["sort_stall"], 1)
+    assert sim["latency_p50_cycles"] == round(
+        float(np.percentile(service, 50)), 1)
+    assert sim["latency_p99_cycles"] == round(
+        float(np.percentile(service, 99)), 1)

@@ -28,8 +28,7 @@ from repro.serve.scenes import DEFAULT_SCENE_BUCKETS
 
 _RECORD_FIELDS = ("is_full", "n_gaussians", "candidate_pairs", "raw_pairs",
                   "sort_pairs", "raster_pairs", "active",
-                  "tiles_interpolated", "overflow_pairs", "overflow_tiles",
-                  "block_of_tile", "order_in_block", "block_load")
+                  "tiles_interpolated", "overflow_pairs", "overflow_tiles")
 
 
 def _poses(n, dx=0.0):
